@@ -60,6 +60,7 @@ from repro.core.predictor import PredictorConfig
 from repro.core.session import SimNet
 from repro.core.simulator import SimConfig
 from repro.des.o3 import A64FX_CONFIG, O3Config
+from repro.serving.compile_cache import enable_persistent_cache
 from repro.serving.service import QueueFull, SimServe
 
 O3_CONFIGS = {"default": None, "a64fx": A64FX_CONFIG}
@@ -464,16 +465,20 @@ def cmd_fleet(args) -> int:
         for job in spec.get("jobs", []):
             if "n" in job:
                 job["n"] = min(int(job["n"]), 2000)
-    fleet = Fleet(
-        args.replicas,
-        models=spec.get("models"),
-        router_port=args.http,
-        max_queue_depth=args.max_queue_depth,
-        max_wait_ms=args.max_wait_ms,
-        chunk=args.chunk,
-        cache_dir=args.cache_dir,
-        startup_timeout_s=args.startup_timeout,
-    )
+    try:
+        fleet = Fleet(
+            args.replicas,
+            models=spec.get("models"),
+            router_port=args.http,
+            max_queue_depth=args.max_queue_depth,
+            max_wait_ms=args.max_wait_ms,
+            chunk=args.chunk,
+            cache_dir=args.cache_dir,
+            startup_timeout_s=args.startup_timeout,
+        )
+    except ValueError as e:  # e.g. more replicas than TPU chips
+        print(f"repro fleet: {e}", file=sys.stderr)
+        return 2
     with fleet:
         port = fleet.router.port
         entries = route_jobs(fleet.url, _job_payloads(spec, args),
@@ -736,7 +741,8 @@ def build_parser() -> argparse.ArgumentParser:
                    help="JSON job file (same shape as `serve`); jobs are "
                         "POSTed through the router as a real HTTP client")
     p.add_argument("--replicas", type=int, default=2,
-                   help="SimServe replica subprocesses to spawn")
+                   help="SimServe replica subprocesses to spawn (on a "
+                        "TPU host: one chip each, at most one per chip)")
     p.add_argument("--http", type=int, default=0, metavar="PORT",
                    help="router port (0 = ephemeral; replicas always bind "
                         "ephemeral ports)")
@@ -805,6 +811,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    enable_persistent_cache()
     if getattr(args, "bench", None) is None:
         args.bench = getattr(args, "bench_default", None)
     if getattr(args, "faults", None) is None:

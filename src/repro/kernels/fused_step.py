@@ -7,12 +7,19 @@ the unfused path materializes a fresh recency-ordered (L, 1+Q, 50) tensor
 every instruction just to feed the conv trunk.
 
 This kernel removes that term. A lane-tile's ring-buffer planes are read
-into VMEM ONCE; the recency reorder (a flip + cyclic roll by the global
-head cursor), the dependency-flag compare against the current instruction,
-the dynamic-feature concat, the sequence/channel padding, and all three
-k2s2 conv layers of the C3 trunk happen register/VMEM-resident. The
-assembled (TB, 1+Q, 50) input never touches HBM; HBM traffic is exactly
-the state-plane reads + one (TB, N/8, C3) activation write per tile.
+into VMEM ONCE; the dependency-flag compare against the current
+instruction, the dynamic-feature concat, the reorder by the global head
+cursor, the sequence/channel padding, and all three k2s2 conv layers of
+the C3 trunk happen register/VMEM-resident. The assembled input never
+touches HBM; HBM traffic is exactly the state-plane reads + one
+(N/8, TB, C3) activation write per tile.
+
+Reorder. The ring holds the window in chronological order starting at the
+head cursor (slot ``head`` is the oldest entry), so one dynamic sublane
+rotation by the cursor, appending the current instruction, gives the
+chronological window that `cnn_trunk.conv_stack` runs on — no reverse,
+which the TPU compiler does not lower. The cursor arrives as a
+scalar-prefetch operand (SMEM).
 
 The FC head + hybrid decode stay outside (tiny GEMMs on (L, hidden)) —
 see `repro.core.predictor.make_fused_predict_fn`.
@@ -23,27 +30,30 @@ same kernel natively.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.cnn_trunk import conv_stack, weight_specs
 
 
 def _fused_step_kernel(
+    head_ref,
     feat_ref, addr_ref, resid_ref, exec_ref, store_ref, valid_ref,
-    head_ref, curf_ref, cura_ref,
+    curf_ref, cura_ref,
     w1, b1, w2, b2, w3, b3,
     o_ref, *, lat_scale: float, seq_padded: int,
 ):
     TB, Q, CF = feat_ref.shape
-    head = head_ref[0]
+    c_pad = w1.shape[0] // 2  # the (pre-padded) first conv's input width
 
     # dynamic features + dependency flags, in physical slot order
-    # (elementwise ops commute with the recency permutation)
     valid_f = valid_ref[...].astype(jnp.float32)  # (TB, Q)
-    dep = jnp.logical_and(
-        addr_ref[...] == cura_ref[...][:, None, :],
-        cura_ref[...][:, None, :] != 0,
-    ).astype(jnp.float32)  # (TB, Q, 5)
+    cura = cura_ref[...][:, None, :]
+    dep = jnp.logical_and(addr_ref[...] == cura, cura != 0).astype(jnp.float32)
     ctx = jnp.concatenate(
         [
             feat_ref[...],
@@ -55,41 +65,36 @@ def _fused_step_kernel(
         ],
         axis=-1,
     )  # (TB, Q, 50)
+    nf = ctx.shape[-1]
     ctx = ctx * valid_f[..., None]  # zero padding rows entirely
+    ctx = jnp.concatenate([ctx, jnp.zeros((TB, Q, c_pad - nf), jnp.float32)], axis=-1)
 
-    # physical → recency order: slot (head-1-r) mod Q holds recency r,
-    # i.e. a cyclic roll by -head followed by a flip (no gather needed —
-    # on TPU this is two dynamic slices + a reverse)
-    ctx = jnp.flip(jnp.roll(ctx, -head, axis=1), axis=1)
+    # physical → chronological: slot (head + i) mod Q holds the i-th oldest
+    ctx = pltpu.roll(ctx, (Q - head_ref[0]) % Q, 1)
+    ctx = jnp.swapaxes(ctx, 0, 1)  # (Q, TB, c_pad) sequence-major
 
     # current-instruction row: static block + zero dynamics + valid flag
-    nf = ctx.shape[-1]
     cur = jnp.concatenate(
         [
             curf_ref[...],
             jnp.zeros((TB, nf - CF - 1), jnp.float32),
             jnp.ones((TB, 1), jnp.float32),
+            jnp.zeros((TB, c_pad - nf), jnp.float32),
         ],
         axis=-1,
-    )  # (TB, 50)
-    x = jnp.concatenate([cur[:, None, :], ctx], axis=1)  # (TB, 1+Q, 50)
+    )  # (TB, c_pad)
+    # chronological window: zero padding (older than the ring), the ring
+    # oldest→newest, then the current instruction
+    x = jnp.concatenate(
+        [jnp.zeros((seq_padded - 1 - Q, TB, c_pad), jnp.float32), ctx, cur[None]],
+        axis=0,
+    )  # (seq_padded, TB, c_pad)
 
-    # sequence pad to the conv stack's multiple, channel pad to the MXU
-    # lane width the (pre-padded) first conv weight expects
-    c_pad = w1.shape[0] // 2
-    x = jnp.pad(x, ((0, 0), (0, seq_padded - (1 + Q)), (0, c_pad - nf)))
-
-    def layer(h, w_ref, b_ref):
-        tb, n, c = h.shape
-        hr = h.reshape(tb * (n // 2), 2 * c)
-        y = jnp.dot(hr, w_ref[...], preferred_element_type=jnp.float32)
-        y = jax.nn.relu(y + b_ref[...][None, :])
-        return y.reshape(tb, n // 2, -1)
-
-    h = layer(x, w1, b1)
-    h = layer(h, w2, b2)
-    h = layer(h, w3, b3)
-    o_ref[...] = h
+    h = conv_stack(
+        x.reshape(seq_padded * TB, c_pad), [(w1, b1), (w2, b2), (w3, b3)],
+        seq_padded, TB,
+    )
+    o_ref[...] = h.reshape(seq_padded // 8, TB, -1)
 
 
 def fused_step_pallas(
@@ -101,11 +106,9 @@ def fused_step_pallas(
     cur_addr: (B, 5) i32; weights: [(w1, b1), (w2, b2), (w3, b3)] with the
     first weight's input side pre-padded to the kernel's channel pad.
 
-    Returns (B, seq_padded//8, C3). B must divide by lane_tile (ops.py
-    pads); seq_padded by 8 (three stride-2 stages).
+    Returns (B, seq_padded//8, C3) in recency order. B must divide by
+    lane_tile (ops.py pads); seq_padded by 8 (three stride-2 stages).
     """
-    import functools
-
     from repro.core.features import LAT_SCALE
 
     B, Q, CF = feat.shape
@@ -114,34 +117,32 @@ def fused_step_pallas(
     c3 = weights[2][0].shape[1]
     TB = min(lane_tile, B)
     assert B % TB == 0, (B, TB)
-    grid = (B // TB,)
-    lane2 = lambda shape: pl.BlockSpec(shape, lambda i: (i, 0))
-    lane3 = lambda shape: pl.BlockSpec(shape, lambda i: (i, 0, 0))
+    lane2 = lambda shape: pl.BlockSpec(shape, lambda i, head: (i, 0))
+    lane3 = lambda shape: pl.BlockSpec(shape, lambda i, head: (i, 0, 0))
     in_specs = [
         lane3((TB, Q, CF)),                    # feat
         lane3((TB, Q, addr.shape[2])),         # addr
         lane2((TB, Q)), lane2((TB, Q)), lane2((TB, Q)),  # resid/exec/store
         lane2((TB, Q)),                        # valid
-        pl.BlockSpec((1,), lambda i: (0,)),    # head
         lane2((TB, CF)),                       # cur_feat
         lane2((TB, cur_addr.shape[1])),        # cur_addr
     ]
-    flat = []
-    for w, b in weights:
-        flat += [w, b]
-        in_specs += [
-            pl.BlockSpec(w.shape, lambda i: (0, 0)),
-            pl.BlockSpec(b.shape, lambda i: (0,)),
-        ]
+    flat, w_specs = weight_specs(weights, lambda i, head: (0, 0))
     kernel = functools.partial(
         _fused_step_kernel, lat_scale=LAT_SCALE, seq_padded=seq_padded
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((TB, seq_padded // 8, c3), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, seq_padded // 8, c3), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B // TB,),
+            in_specs=in_specs + w_specs,
+            out_specs=pl.BlockSpec(
+                (seq_padded // 8, TB, c3), lambda i, head: (0, i, 0)
+            ),
+        ),
+        out_shape=jax.ShapeDtypeStruct((seq_padded // 8, B, c3), jnp.float32),
         interpret=interpret,
-    )(feat, addr, resid, exec_lat, store_lat, valid, head, cur_feat, cur_addr,
+    )(head, feat, addr, resid, exec_lat, store_lat, valid, cur_feat, cur_addr,
       *flat)
+    return jnp.flip(out, 0).transpose(1, 0, 2)

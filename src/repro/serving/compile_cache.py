@@ -38,12 +38,33 @@ from __future__ import annotations
 
 import concurrent.futures
 import dataclasses
+import os
 import threading
 import time
+from pathlib import Path
 from typing import Any, Callable, Dict, Optional, Tuple
+
+import jax
 
 from repro.core.predictor import PredictorConfig
 from repro.core.simulator import SimConfig
+
+# <checkout>/.jax_cache, from this file's place in <checkout>/src/repro/serving/
+DEFAULT_PERSISTENT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent (on-disk) compilation cache for this
+    process and return its directory, so a cold process reuses what an
+    earlier one compiled. ``JAX_COMPILATION_CACHE_DIR`` wins when set;
+    otherwise the cache lives at a fixed path in the checkout — fixed
+    because the directory is part of what a later process must find.
+
+    Entry points call this before their first compile; it is never run
+    at import, so library users and the tests stay cache-free."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(DEFAULT_PERSISTENT_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def lane_bucket(n_lanes: int) -> int:

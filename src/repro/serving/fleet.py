@@ -22,10 +22,17 @@ router over the collected URLs. Any replica failing to come up tears the
 whole fleet down — no orphan subprocesses — with that replica's stderr
 tail in the raised error.
 
+One chip per replica. On a TPU host every replica child is pinned to its
+own chip through libtpu's per-process settings (`chip_env`), a fleet
+larger than the host's chip count is refused up front, and a fleet started
+from a process that already holds the TPU is refused too: its children
+could never open a chip the parent keeps.
+
 Shell entry: ``python -m repro fleet --replicas N --jobs jobs.json``.
 """
 from __future__ import annotations
 
+import glob
 import json
 import os
 import select
@@ -61,6 +68,50 @@ def _repro_env() -> Dict[str, str]:
     return env
 
 
+def tpu_chip_count() -> int:
+    """TPU chips this process's replica children can be given, counted
+    without opening the TPU runtime (this process must stay off the chips
+    it hands out): 0 when JAX is held to other platforms (``JAX_PLATFORMS``
+    without ``tpu``) or the PCI bus shows no TPU, else the chips' device
+    files (``/dev/accel*`` on v4/v5p, ``/dev/vfio/<n>`` on v5e and later).
+    The device files, not the bus, say how many chips a container may
+    open: a one-chip slice of a four-chip host lists four on the bus."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    from jax._src import hardware_utils
+
+    on_bus, _ = hardware_utils.num_available_tpu_chips_and_device_id()
+    if not on_bus:
+        return 0
+    return min(on_bus, len(glob.glob("/dev/accel[0-9]*") + glob.glob("/dev/vfio/[0-9]*")))
+
+
+def holds_tpu() -> bool:
+    """True once this process has opened a TPU runtime (libtpu lets one
+    process at a time own a chip, so a child asking for it would fail or
+    hang). Reads JAX's backend table without initializing it."""
+    import jax
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized() and jax.default_backend() == "tpu"
+
+
+def chip_env(chip: int) -> Dict[str, str]:
+    """libtpu settings that give one process exactly chip ``chip`` of
+    this host: a one-chip slice of its own (the bounds, which also lift
+    libtpu's one-process-per-host lock), the chip it sees, and a
+    slice-builder port no sibling uses."""
+    port = 8476 + chip
+    return {
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_VISIBLE_CHIPS": str(chip),
+        "TPU_PROCESS_PORT": str(port),
+        "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+    }
+
+
 class ReplicaSpawnError(RuntimeError):
     """A replica subprocess died or never announced its port."""
 
@@ -89,6 +140,7 @@ class ReplicaProcess:
         stop_grace_s: float = 10.0,
         batch_timeout_s: float = 0.0,
         faults_spec: Optional[str] = None,
+        chip: Optional[int] = None,
     ):
         self.name = name
         self.models = dict(models or {})
@@ -104,6 +156,8 @@ class ReplicaProcess:
         # installed by the child's CLI entry — independent of any plan in
         # this driver process)
         self.faults_spec = faults_spec
+        # the TPU chip this replica owns (None: no chips on this host)
+        self.chip = chip
         self._log_dir = log_dir or tempfile.mkdtemp(prefix="repro-fleet-")
         self.stderr_path = Path(self._log_dir) / f"{self.name}.stderr.log"
         self._cmd_override = list(cmd) if cmd is not None else None
@@ -130,6 +184,12 @@ class ReplicaProcess:
             cmd += ["--cache-dir", str(Path(self.cache_dir) / self.name)]
         return cmd
 
+    def env(self) -> Dict[str, str]:
+        env = _repro_env()
+        if self.chip is not None:
+            env.update(chip_env(self.chip))
+        return env
+
     @property
     def url(self) -> str:
         return f"http://127.0.0.1:{self.port}"
@@ -150,7 +210,7 @@ class ReplicaProcess:
         # read() agree (a Python-side buffer would hide ready bytes)
         self._proc = subprocess.Popen(
             self.command(), stdout=subprocess.PIPE, stderr=self._stderr_f,
-            stdin=subprocess.DEVNULL, env=_repro_env(), bufsize=0,
+            stdin=subprocess.DEVNULL, env=self.env(), bufsize=0,
         )
         log_event("fleet.spawn", level=logging.INFO, replica=self.name,
                   pid=self._proc.pid, cmd=self.command())
@@ -274,6 +334,12 @@ class Fleet:
                 f"models_per_replica has {len(models_per_replica)} entries "
                 f"for {n_replicas} replicas"
             )
+        n_chips = tpu_chip_count()
+        if n_chips and n_replicas > n_chips:
+            raise ValueError(
+                f"{n_replicas} replicas need {n_replicas} TPU chips (one "
+                f"each) but this host has {n_chips}"
+            )
         self.startup_timeout_s = float(startup_timeout_s)
         self.router_port = int(router_port)
         self._router_kw = dict(
@@ -290,6 +356,7 @@ class Fleet:
                 chunk=chunk, cache_dir=cache_dir, log_dir=log_dir,
                 stop_grace_s=stop_grace_s, batch_timeout_s=batch_timeout_s,
                 faults_spec=replica_faults,
+                chip=i if n_chips else None,
             )
             for i in range(n_replicas)
         ]
@@ -318,6 +385,12 @@ class Fleet:
     def start(self) -> "Fleet":
         if self.router is not None:
             return self
+        if any(r.chip is not None for r in self.replicas) and holds_tpu():
+            raise RuntimeError(
+                "this process holds the TPU, so no replica child could open "
+                "its chip: start the fleet from a process that has not run "
+                "JAX on the TPU"
+            )
         try:
             for r in self.replicas:
                 r.spawn()  # all interpreters boot in parallel...

@@ -267,7 +267,11 @@ class SimNetEngine:
                 f"{self.sim_cfg.ctx_len} (the predictor input width is fixed)"
             )
         n_live = packed.n_lanes
-        packed = pad_packed_lanes(packed, lane_bucket(n_live))
+        n_lanes = lane_bucket(n_live)
+        if self.mesh is not None:  # every device holds an equal lane slice
+            per = int(np.prod([self.mesh.shape[a] for a in _lane_axes(self.mesh)]))
+            n_lanes = -(-n_lanes // per) * per
+        packed = pad_packed_lanes(packed, n_lanes)
         self._stage_params()
         exe = self.executable(packed.n_lanes, chunk)
 

@@ -1,10 +1,19 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — tests run on the real single
 CPU device; only launch/dryrun.py forces 512 virtual devices."""
-import numpy as np
-import pytest
+import os
 
-from repro.des.o3 import O3Config, O3Simulator
-from repro.des.workloads import get_benchmark
+# JAX's persistent compile cache stays off in the tests and in every process
+# they start: `repro` entry points (cli.main) turn it on for real runs.
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+
+import jax  # noqa: E402
+import numpy as np  # noqa: E402
+import pytest  # noqa: E402
+
+jax.config.update("jax_enable_compilation_cache", False)
+
+from repro.des.o3 import O3Config, O3Simulator  # noqa: E402
+from repro.des.workloads import get_benchmark  # noqa: E402
 
 
 def synth_arrays(T, seed):

@@ -16,6 +16,7 @@ import time
 
 import pytest
 
+from repro.serving import fleet as fleet_mod
 from repro.serving.fleet import Fleet, ReplicaProcess, ReplicaSpawnError, _repro_env
 
 
@@ -133,6 +134,63 @@ def test_repro_env_prepends_src():
     assert os.path.isdir(os.path.join(first, "repro"))
     assert first in {os.path.dirname(os.path.abspath(p))
                      for p in repro.__path__}
+
+
+# ------------------------------------------------------- one chip each
+
+def test_fleet_pins_one_tpu_chip_per_replica(tmp_path, monkeypatch):
+    """On a TPU host each replica child gets exactly its own chip:
+    a one-chip slice, its chip index, and a slice-builder port of its own."""
+    monkeypatch.setattr(fleet_mod, "tpu_chip_count", lambda: 4)
+    fleet = Fleet(2)
+    assert [r.chip for r in fleet.replicas] == [0, 1]
+    envs = [r.env() for r in fleet.replicas]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1"]
+    for e in envs:
+        assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        assert e["TPU_PROCESS_BOUNDS"] == "1,1,1"
+        assert e["PYTHONPATH"] == _repro_env()["PYTHONPATH"]
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 2
+    # the spawned child really runs with its chip's settings
+    r = fleet.replicas[1]
+    r._cmd_override = [sys.executable, "-c",
+                       "import os, sys; sys.exit(0 if os.environ"
+                       "['TPU_VISIBLE_CHIPS'] == '1' else 7)"]
+    r.spawn()
+    try:
+        assert r._proc.wait(timeout=60) == 0
+    finally:
+        r.stop()
+
+
+def test_fleet_without_tpu_chips_pins_nothing(monkeypatch):
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert fleet_mod.tpu_chip_count() == 0
+    fleet = Fleet(3)
+    assert [r.chip for r in fleet.replicas] == [None] * 3
+    assert fleet.replicas[0].env() == _repro_env()
+
+
+def test_fleet_refuses_more_replicas_than_chips(tmp_path, monkeypatch, capsys):
+    from repro.cli import main
+
+    monkeypatch.setattr(fleet_mod, "tpu_chip_count", lambda: 1)
+    with pytest.raises(ValueError, match="2 replicas need 2 TPU chips"):
+        Fleet(2)
+    jobs = tmp_path / "jobs.json"
+    jobs.write_text(json.dumps({"jobs": [{"id": "a", "bench": "sim_loop"}]}))
+    assert main(["fleet", "--replicas", "2", "--jobs", str(jobs)]) == 2
+    assert "this host has 1" in capsys.readouterr().err
+
+
+def test_fleet_refuses_to_start_from_a_process_holding_the_tpu(monkeypatch):
+    monkeypatch.setattr(fleet_mod, "tpu_chip_count", lambda: 2)
+    monkeypatch.setattr(fleet_mod, "holds_tpu", lambda: True)
+    fleet = Fleet(2)
+    with pytest.raises(RuntimeError, match="holds the TPU"):
+        fleet.start()
+    assert all(r.pid is None for r in fleet.replicas)  # nothing was spawned
+    assert fleet.router is None
 
 
 # ------------------------------------------------------------- CLI smoke

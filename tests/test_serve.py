@@ -72,6 +72,52 @@ def test_compile_cache_counts_hits_and_misses():
     assert cache.stats()["n_executables"] == 0
 
 
+def test_persistent_cache_honours_env_dir(tmp_path, monkeypatch):
+    import jax
+
+    from repro.serving.compile_cache import enable_persistent_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        assert enable_persistent_cache() == str(tmp_path / "jc")
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path / "jc")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_persistent_cache_default_is_fixed_in_the_checkout(tmp_path, monkeypatch):
+    """Unset, the cache sits at <checkout>/.jax_cache whatever the cwd or
+    the process: another process in another directory finds the same one."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    import jax
+
+    from repro.serving.compile_cache import enable_persistent_cache
+
+    checkout = Path(__file__).resolve().parents[1]
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.chdir(tmp_path)
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        here = enable_persistent_cache()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    assert here == str(checkout / ".jax_cache")
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = str(checkout / "src")
+    other = subprocess.run(
+        [sys.executable, "-c", "from repro.serving.compile_cache import "
+         "enable_persistent_cache as e; print(e())"],
+        cwd=tmp_path.parent, env=env, capture_output=True, text=True, timeout=120,
+    )
+    assert other.returncode == 0, other.stderr[-2000:]
+    assert other.stdout.strip().splitlines()[-1] == here
+
+
 # --------------------------------------------------- service vs session
 
 def test_service_matches_session_bit_identical(traces):

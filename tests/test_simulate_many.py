@@ -1,5 +1,11 @@
 """Batched multi-workload engine: packing round-trip, ragged masking,
 per-workload overflow accounting, heterogeneous SimConfigs, engine parity."""
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -190,3 +196,39 @@ def test_engine_simulate_many_matches_core(traces):
     )
     assert res_e["n_workloads"] == 2
     assert res_e["total_instructions"] == int(np.sum(res_c["n_instructions"]))
+
+
+_SHARDED_SCRIPT = """
+import json
+from repro.core.api import SimNet
+from repro.des.o3 import O3Config, O3Simulator
+from repro.des.workloads import get_benchmark
+from repro.launch.mesh import make_host_mesh
+trs = [O3Simulator(O3Config()).run(get_benchmark(n, 3000))
+       for n in ("mlb_stream", "sim_loop")]
+mesh = make_host_mesh()
+many = SimNet(mesh=mesh).simulate_many(trs, n_lanes=1)
+one = SimNet().simulate_many(trs, n_lanes=1)
+print(json.dumps({"devices": int(mesh.devices.size),
+                  "des": [t.total_cycles for t in trs],
+                  "sharded": [w.total_cycles for w in many.workloads],
+                  "single": [w.total_cycles for w in one.workloads]}))
+"""
+
+
+def test_lane_sharded_session_matches_des_and_one_device():
+    """SimNet over a 4-device lane mesh (virtual CPU devices, set before
+    JAX starts, so in a subprocess): teacher-forced totals equal the DES
+    and the one-device run. Two one-lane workloads also check that a pack
+    narrower than the mesh is padded to one lane per device."""
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join(
+                   p for p in (src, os.environ.get("PYTHONPATH")) if p))
+    res = subprocess.run([sys.executable, "-c", _SHARDED_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert res.returncode == 0, res.stderr[-3000:]
+    out = json.loads(res.stdout.strip().splitlines()[-1])
+    assert out["devices"] == 4
+    assert out["sharded"] == out["des"] == out["single"]
