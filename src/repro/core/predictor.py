@@ -289,8 +289,10 @@ def apply_trunk(params, x, cfg: PredictorConfig, use_kernel: bool = False):
 # repro-lint: scan-reachable — called from the sim-step under lax.scan
 def apply_raw(params, x, cfg: PredictorConfig, use_kernel: bool = False):
     """(B, N, 50) -> raw head outputs (B, out_dim)."""
-    h, head = apply_trunk(params, x, cfg, use_kernel=use_kernel)
-    return _dense(head, h)
+    with jax.named_scope("trunk"):
+        h, head = apply_trunk(params, x, cfg, use_kernel=use_kernel)
+    with jax.named_scope("head"):
+        return _dense(head, h)
 
 
 def split_heads(raw, cfg: PredictorConfig):
@@ -306,13 +308,15 @@ def split_heads(raw, cfg: PredictorConfig):
 def decode_latency(raw, cfg: PredictorConfig):
     """Hybrid decode (paper §2.3): argmax class if < overflow else regression.
     Returns (B, 3) float latencies (regression head is in REG_SCALE space)."""
-    cls_logits, reg = split_heads(raw, cfg)
-    reg = jax.nn.relu(reg) / REG_SCALE
-    if cls_logits is None:
-        return reg
-    cls = jnp.argmax(cls_logits, axis=-1)
-    overflow = cls == (cfg.n_classes - 1)
-    return jnp.where(overflow, jnp.maximum(reg, float(cfg.n_classes - 1)), cls.astype(jnp.float32))
+    with jax.named_scope("head"):
+        cls_logits, reg = split_heads(raw, cfg)
+        reg = jax.nn.relu(reg) / REG_SCALE
+        if cls_logits is None:
+            return reg
+        cls = jnp.argmax(cls_logits, axis=-1)
+        overflow = cls == (cfg.n_classes - 1)
+        return jnp.where(overflow, jnp.maximum(reg, float(cfg.n_classes - 1)),
+                         cls.astype(jnp.float32))
 
 
 def make_predict_fn(params, cfg: PredictorConfig, use_kernel: bool = False):
@@ -352,7 +356,8 @@ def make_fused_predict_fn(params, cfg: PredictorConfig):
         )
         h = h.reshape(h.shape[0], -1).astype(jnp.float32)
         h = _dense(params["fc0"], h, act="relu")
-        raw = _dense(params["fc1"], h)
+        with jax.named_scope("head"):
+            raw = _dense(params["fc1"], h)
         return decode_latency(raw, cfg)
 
     return predict

@@ -461,23 +461,32 @@ def make_sim_scan(
     multi-workload path uses this so memory stays O(state), not O(T).
     """
 
+    # Named scopes (HLO metadata only, the same instructions): `assembly`,
+    # `retire` here and `trunk`, `head` in the predictor, so a profile of
+    # the device splits each step's time between them.
     # repro-lint: scan-reachable — runs under lax.scan inside jit
     def step(state, xs):
         cur = {"feat": xs["feat"], "addr": xs["addr"], "is_store": xs["is_store"]}
         if predict_state_fn is not None:
-            lats = predict_state_fn(state, cur["feat"], cur["addr"])
+            # the fused kernel assembles its input inside the trunk
+            with jax.named_scope("trunk"):
+                lats = predict_state_fn(state, cur["feat"], cur["addr"])
             out = {"lats": lats} if emit_outputs else {}
         elif predict_fn is None:
             lats = xs["labels"]
-            out = {"x": model_input(state, cur["feat"], cur["addr"], cfg)} if emit_outputs else {}
+            with jax.named_scope("assembly"):
+                out = ({"x": model_input(state, cur["feat"], cur["addr"], cfg)}
+                       if emit_outputs else {})
         else:
-            x = model_input(state, cur["feat"], cur["addr"], cfg)
+            with jax.named_scope("assembly"):
+                x = model_input(state, cur["feat"], cur["addr"], cfg)
             lats = predict_fn(x)  # sim_step zeroes store latency for non-stores
             out = {"lats": lats} if emit_outputs else {}
-        new_state = sim_step(
-            state, cur, lats, cfg,
-            active=xs.get("active"), retire_width=retire_width, lane_ctx=lane_ctx,
-        )
+        with jax.named_scope("retire"):
+            new_state = sim_step(
+                state, cur, lats, cfg,
+                active=xs.get("active"), retire_width=retire_width, lane_ctx=lane_ctx,
+            )
         return new_state, out
 
     return step

@@ -74,7 +74,7 @@ from repro.serving.compile_cache import (
 from repro.serving import faults
 from repro.serving.registry import ModelRegistry
 from repro.serving.simnet_engine import NumericError
-from repro.serving.telemetry import Telemetry, log_event, new_correlation_id
+from repro.serving.telemetry import Telemetry, log_event, new_correlation_id, span
 
 
 class BatchTimeout(RuntimeError):
@@ -124,6 +124,13 @@ class BatchReport:
     first_call_seconds: float
     throughput_ips: float
     cache: Dict[str, Any]  # hit/miss/compile-seconds delta of this batch
+    # host seconds of each phase, timed by its `telemetry.span`: the jobs'
+    # featurizing at submit, then the engine's first pass
+    featurize_seconds: float = 0.0
+    pack_seconds: float = 0.0
+    stage_seconds: float = 0.0  # host-to-device puts and chunk enqueues
+    device_wait_seconds: float = 0.0  # host blocked on the device
+    results_seconds: float = 0.0  # host copies, numeric guard, per-job results
 
     def to_dict(self) -> Dict[str, Any]:
         d = dataclasses.asdict(self)
@@ -145,6 +152,7 @@ class _Job:
     priority: int = 0
     deadline_ms: Optional[float] = None
     submit_t: float = 0.0  # service-clock timestamp of admission
+    featurize_seconds: float = 0.0  # host time of F.trace_arrays at submit
     corr_id: str = ""  # correlation id stamped on every log record
     result: Optional[WorkloadResult] = None
     batch: Optional[BatchReport] = None
@@ -463,110 +471,118 @@ class SimServe:
         Raises `QueueFull` when ``max_queue_depth`` pending jobs are
         already buffered and `ModelUnavailable` when the model's circuit
         breaker is open — nothing is enqueued in either case."""
-        if model_id is None:
-            model_id = self.registry.ensure_teacher_forced()
-        elif model_id not in self.registry:
-            raise KeyError(
-                f"no resident model {model_id!r}; register() it first "
-                f"(registered: {sorted(self.registry.ids())})"
-            )
-        if sim_cfg is not None:
-            # ctx_len / retire_width replay per lane inside the pack; every
-            # other SimConfig field is baked into the resident executable —
-            # a mismatch must fail loudly here, not simulate with the
-            # engine's values
-            eng_cfg = self.registry.get(model_id).sim_cfg
-            if sim_cfg.layout != eng_cfg.layout:
-                # the step layout is compiled into the resident executable
-                # (it rides the compile-cache key) and cannot replay per
-                # lane — name it specifically rather than the generic
-                # config-mismatch message below
-                raise ValueError(
-                    f"job SimConfig layout {sim_cfg.layout!r} differs from "
-                    f"resident model {model_id!r} layout {eng_cfg.layout!r}: "
-                    "a resident engine runs ONE step layout — submit with "
-                    "the engine's layout or register a model with the "
-                    "wanted one"
+        with span("simnet.submit") as ann:
+            if model_id is None:
+                model_id = self.registry.ensure_teacher_forced()
+            elif model_id not in self.registry:
+                raise KeyError(
+                    f"no resident model {model_id!r}; register() it first "
+                    f"(registered: {sorted(self.registry.ids())})"
                 )
-            if dataclasses.replace(
-                sim_cfg, ctx_len=eng_cfg.ctx_len, retire_width=eng_cfg.retire_width
-            ) != eng_cfg:
+            if sim_cfg is not None:
+                # ctx_len / retire_width replay per lane inside the pack; every
+                # other SimConfig field is baked into the resident executable —
+                # a mismatch must fail loudly here, not simulate with the
+                # engine's values
+                eng_cfg = self.registry.get(model_id).sim_cfg
+                if sim_cfg.layout != eng_cfg.layout:
+                    # the step layout is compiled into the resident executable
+                    # (it rides the compile-cache key) and cannot replay per
+                    # lane — name it specifically rather than the generic
+                    # config-mismatch message below
+                    raise ValueError(
+                        f"job SimConfig layout {sim_cfg.layout!r} differs from "
+                        f"resident model {model_id!r} layout {eng_cfg.layout!r}: "
+                        "a resident engine runs ONE step layout — submit with "
+                        "the engine's layout or register a model with the "
+                        "wanted one"
+                    )
+                if dataclasses.replace(
+                    sim_cfg, ctx_len=eng_cfg.ctx_len, retire_width=eng_cfg.retire_width
+                ) != eng_cfg:
+                    raise ValueError(
+                        f"job SimConfig {sim_cfg} is incompatible with resident "
+                        f"model {model_id!r} ({eng_cfg}): only ctx_len/retire_width "
+                        "may differ — register a model with the wanted config"
+                    )
+                if sim_cfg.ctx_len > eng_cfg.ctx_len:
+                    raise ValueError(
+                        f"job ctx_len {sim_cfg.ctx_len} exceeds resident model "
+                        f"{model_id!r} ctx_len {eng_cfg.ctx_len} (the predictor "
+                        "input width is fixed)"
+                    )
+            featurize: Dict[str, float] = {}
+            if isinstance(trace, dict):
+                arrs = trace
+            else:
+                with span("simnet.featurize", featurize, "seconds"):
+                    arrs = F.trace_arrays(trace)
+            T = int(arrs["feat"].shape[0])
+            if not 1 <= n_lanes <= T:
+                # statically invalid jobs must be refused here — at drain they
+                # would detonate the shared batch and poison valid batchmates
                 raise ValueError(
-                    f"job SimConfig {sim_cfg} is incompatible with resident "
-                    f"model {model_id!r} ({eng_cfg}): only ctx_len/retire_width "
-                    "may differ — register a model with the wanted config"
+                    f"n_lanes={n_lanes} invalid for a {T}-instruction workload "
+                    "(need 1 <= n_lanes <= instructions)"
                 )
-            if sim_cfg.ctx_len > eng_cfg.ctx_len:
-                raise ValueError(
-                    f"job ctx_len {sim_cfg.ctx_len} exceeds resident model "
-                    f"{model_id!r} ctx_len {eng_cfg.ctx_len} (the predictor "
-                    "input width is fixed)"
-                )
-        arrs = trace if isinstance(trace, dict) else F.trace_arrays(trace)
-        T = int(arrs["feat"].shape[0])
-        if not 1 <= n_lanes <= T:
-            # statically invalid jobs must be refused here — at drain they
-            # would detonate the shared batch and poison valid batchmates
-            raise ValueError(
-                f"n_lanes={n_lanes} invalid for a {T}-instruction workload "
-                "(need 1 <= n_lanes <= instructions)"
-            )
-        # circuit breaker: a model that failed its last breaker_threshold
-        # batches is isolated HERE — fast-fail at admission, the drain
-        # loop is never touched. Checked after the static validations so
-        # an invalid request cannot consume the half-open probe slot.
-        if not self.registry.breaker(model_id).allow():
-            with self._qlock:
-                self._jobs_breaker_rejected += 1
-            log_event("job.rejected", level=logging.WARNING,
-                      reason="breaker_open", model=model_id)
-            raise ModelUnavailable(
-                f"model {model_id!r} is isolated: its circuit breaker is "
-                f"open after repeated batch failures "
-                f"({self.registry.breaker(model_id).snapshot()}); retry "
-                "after the cooldown or register a fixed artifact"
-            )
-        with self._qlock:
-            if self.max_queue_depth and len(self._pending) >= self.max_queue_depth:
-                self._jobs_rejected += 1
+            # circuit breaker: a model that failed its last breaker_threshold
+            # batches is isolated HERE — fast-fail at admission, the drain
+            # loop is never touched. Checked after the static validations so
+            # an invalid request cannot consume the half-open probe slot.
+            if not self.registry.breaker(model_id).allow():
+                with self._qlock:
+                    self._jobs_breaker_rejected += 1
                 log_event("job.rejected", level=logging.WARNING,
-                          reason="queue_full", model=model_id,
-                          queue_depth=len(self._pending))
-                raise QueueFull(
-                    f"queue is full ({len(self._pending)} pending >= "
-                    f"max_queue_depth={self.max_queue_depth}); job refused — "
-                    "wait on outstanding handles and retry"
+                          reason="breaker_open", model=model_id)
+                raise ModelUnavailable(
+                    f"model {model_id!r} is isolated: its circuit breaker is "
+                    f"open after repeated batch failures "
+                    f"({self.registry.breaker(model_id).snapshot()}); retry "
+                    "after the cooldown or register a fixed artifact"
                 )
-            job_id = self._next_id
-            self._next_id += 1
-            job = _Job(
-                job_id=job_id,
-                model_id=model_id,
-                trace=trace,
-                arrs=arrs,
-                # the default name derives from the already-unique job_id,
-                # minted under the lock — a shared counter read outside it
-                # minted colliding names under concurrent submits
-                name=name or getattr(trace, "name", None) or f"job{job_id}",
-                n_lanes=int(n_lanes),
-                sim_cfg=sim_cfg,
-                timeit=timeit,
-                chunk=chunk,
-                priority=int(priority),
-                deadline_ms=None if deadline_ms is None else float(deadline_ms),
-                submit_t=self._clock(),
-                corr_id=new_correlation_id(),
-            )
-            self._pending.append(job)
-            self._jobs_submitted += 1
-            depth = len(self._pending)
-        self.telemetry.queue_depth.observe(depth)
-        log_event("job.submit", job_id=job.job_id, correlation_id=job.corr_id,
-                  model=model_id, name=job.name, n_lanes=job.n_lanes,
-                  priority=job.priority, deadline_ms=job.deadline_ms,
-                  queue_depth=depth)
-        self._wake.set()  # the background loop opens its batch window now
-        return JobHandle(self, job)
+            with self._qlock:
+                if self.max_queue_depth and len(self._pending) >= self.max_queue_depth:
+                    self._jobs_rejected += 1
+                    log_event("job.rejected", level=logging.WARNING,
+                              reason="queue_full", model=model_id,
+                              queue_depth=len(self._pending))
+                    raise QueueFull(
+                        f"queue is full ({len(self._pending)} pending >= "
+                        f"max_queue_depth={self.max_queue_depth}); job refused — "
+                        "wait on outstanding handles and retry"
+                    )
+                job_id = self._next_id
+                self._next_id += 1
+                job = _Job(
+                    job_id=job_id,
+                    model_id=model_id,
+                    trace=trace,
+                    arrs=arrs,
+                    # the default name derives from the already-unique job_id,
+                    # minted under the lock — a shared counter read outside it
+                    # minted colliding names under concurrent submits
+                    name=name or getattr(trace, "name", None) or f"job{job_id}",
+                    n_lanes=int(n_lanes),
+                    sim_cfg=sim_cfg,
+                    timeit=timeit,
+                    chunk=chunk,
+                    priority=int(priority),
+                    deadline_ms=None if deadline_ms is None else float(deadline_ms),
+                    submit_t=self._clock(),
+                    featurize_seconds=featurize.get("seconds", 0.0),
+                    corr_id=new_correlation_id(),
+                )
+                self._pending.append(job)
+                self._jobs_submitted += 1
+                depth = len(self._pending)
+            self.telemetry.queue_depth.observe(depth)
+            log_event("job.submit", job_id=job.job_id, correlation_id=job.corr_id,
+                      model=model_id, name=job.name, n_lanes=job.n_lanes,
+                      priority=job.priority, deadline_ms=job.deadline_ms,
+                      queue_depth=depth)
+            self._wake.set()  # the background loop opens its batch window now
+            ann.set_metadata(job_id=job.job_id)
+            return JobHandle(self, job)
 
     def cancel(self, handle: JobHandle) -> bool:
         """Withdraw a still-pending job from the queue (False if it already
@@ -744,67 +760,77 @@ class SimServe:
         return reports
 
     def _run_batch(self, model_id: str, jobs: List[_Job]) -> BatchReport:
-        engine = self.registry.get(model_id)
-        t_dispatch = self._clock()
-        for j in jobs:
-            self.telemetry.queue_wait_ms.observe(
-                (t_dispatch - j.submit_t) * 1000.0
-            )
-        arrs = [j.arrs for j in jobs]
-        lanes = [j.n_lanes for j in jobs]
-        cfgs = [j.sim_cfg or engine.sim_cfg for j in jobs]
-        cap = min(j.chunk or self.chunk for j in jobs)
-        chunk = chunk_bucket(max_packed_steps(arrs, lanes), cap)
-        timeit = jobs[0].timeit
+        with span("simnet.batch", n_jobs=len(jobs)):
+            engine = self.registry.get(model_id)
+            t_dispatch = self._clock()
+            for j in jobs:
+                self.telemetry.queue_wait_ms.observe(
+                    (t_dispatch - j.submit_t) * 1000.0
+                )
+            arrs = [j.arrs for j in jobs]
+            lanes = [j.n_lanes for j in jobs]
+            cfgs = [j.sim_cfg or engine.sim_cfg for j in jobs]
+            cap = min(j.chunk or self.chunk for j in jobs)
+            chunk = chunk_bucket(max_packed_steps(arrs, lanes), cap)
+            timeit = jobs[0].timeit
 
-        def dispatch():
-            # chaos seam: delay_ms simulates a hung dispatch (watchdog
-            # prey), fail an engine that detonates mid-batch
-            faults.fire("batch.execute")
-            return engine.simulate_many(
-                arrs, n_lanes=lanes, chunk=chunk, cfgs=cfgs, timeit=timeit
-            )
+            def dispatch():
+                # chaos seam: delay_ms simulates a hung dispatch (watchdog
+                # prey), fail an engine that detonates mid-batch
+                faults.fire("batch.execute")
+                return engine.simulate_many(
+                    arrs, n_lanes=lanes, chunk=chunk, cfgs=cfgs, timeit=timeit
+                )
 
-        res = self._dispatch_guarded(model_id, jobs, dispatch)
-        report = BatchReport(
-            model_id=model_id,
-            job_ids=tuple(j.job_id for j in jobs),
-            n_jobs=len(jobs),
-            n_live_lanes=int(res["n_live_lanes"]),
-            n_lanes=int(res["n_lanes"]),
-            chunk=chunk,
-            total_instructions=int(res["total_instructions"]),
-            seconds=float(res["seconds"]),
-            first_call_seconds=float(res["first_call_seconds"]),
-            throughput_ips=float(res["throughput_ips"]),
-            cache=dict(res["cache"]),
-        )
-        t_done = self._clock()
-        for i, job in enumerate(jobs):
-            job.result = self._workload_result(job, res, i)
-            job.batch = report
-            job.done_evt.set()  # result is pinned — waiters may wake now
-            self.telemetry.service_ms.observe((t_done - job.submit_t) * 1000.0)
-            log_event("job.complete", job_id=job.job_id,
-                      correlation_id=job.corr_id, model=model_id,
-                      name=job.name, total_cycles=job.result.total_cycles,
-                      latency_ms=(t_done - job.submit_t) * 1000.0)
-        self.telemetry.batch_jobs.observe(len(jobs))
-        self.registry.breaker(model_id).record_success()
-        log_event("batch.dispatch", model=model_id, n_jobs=len(jobs),
-                  n_live_lanes=report.n_live_lanes, n_lanes=report.n_lanes,
-                  seconds=report.seconds,
-                  correlation_ids=[j.corr_id for j in jobs])
-        with self._qlock:  # concurrent drains must not lose counter updates
-            self._jobs_completed += len(jobs)
-            self._lanes_live += report.n_live_lanes
-            self._lanes_dispatched += report.n_lanes
-            self._dead_lane_steps += (
-                report.n_lanes - report.n_live_lanes
-            ) * int(res["n_steps"])  # padded steps the dispatch actually ran
-            self._n_batches += 1
-            self._batches.append(report)
-        return report
+            res = self._dispatch_guarded(model_id, jobs, dispatch)
+            # the results first, then the report that times them; waiters wake
+            # only once both are pinned
+            with span("simnet.results", res, "results_seconds"):
+                results = [self._workload_result(job, res, i) for i, job in enumerate(jobs)]
+            report = BatchReport(
+                model_id=model_id,
+                job_ids=tuple(j.job_id for j in jobs),
+                n_jobs=len(jobs),
+                n_live_lanes=int(res["n_live_lanes"]),
+                n_lanes=int(res["n_lanes"]),
+                chunk=chunk,
+                total_instructions=int(res["total_instructions"]),
+                seconds=float(res["seconds"]),
+                first_call_seconds=float(res["first_call_seconds"]),
+                throughput_ips=float(res["throughput_ips"]),
+                cache=dict(res["cache"]),
+                featurize_seconds=sum(j.featurize_seconds for j in jobs),
+                pack_seconds=float(res["pack_seconds"]),
+                stage_seconds=float(res["stage_seconds"]),
+                device_wait_seconds=float(res["device_wait_seconds"]),
+                results_seconds=float(res["results_seconds"]),
+            )
+            t_done = self._clock()
+            for job, result in zip(jobs, results):
+                job.result = result
+                job.batch = report
+                job.done_evt.set()  # result is pinned — waiters may wake now
+                self.telemetry.service_ms.observe((t_done - job.submit_t) * 1000.0)
+                log_event("job.complete", job_id=job.job_id,
+                          correlation_id=job.corr_id, model=model_id,
+                          name=job.name, total_cycles=job.result.total_cycles,
+                          latency_ms=(t_done - job.submit_t) * 1000.0)
+            self.telemetry.batch_jobs.observe(len(jobs))
+            self.registry.breaker(model_id).record_success()
+            log_event("batch.dispatch", model=model_id, n_jobs=len(jobs),
+                      n_live_lanes=report.n_live_lanes, n_lanes=report.n_lanes,
+                      seconds=report.seconds,
+                      correlation_ids=[j.corr_id for j in jobs])
+            with self._qlock:  # concurrent drains must not lose counter updates
+                self._jobs_completed += len(jobs)
+                self._lanes_live += report.n_live_lanes
+                self._lanes_dispatched += report.n_lanes
+                self._dead_lane_steps += (
+                    report.n_lanes - report.n_live_lanes
+                ) * int(res["n_steps"])  # padded steps the dispatch actually ran
+                self._n_batches += 1
+                self._batches.append(report)
+            return report
 
     def _dispatch_guarded(self, model_id: str, jobs: List[_Job], dispatch):
         """Run one engine dispatch under the batch watchdog.

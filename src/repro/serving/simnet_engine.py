@@ -58,6 +58,7 @@ from repro.serving.compile_cache import (
     lane_bucket,
     mesh_fingerprint,
 )
+from repro.serving.telemetry import span
 
 
 class NumericError(RuntimeError):
@@ -254,26 +255,38 @@ class SimNetEngine:
         one executable. timeit=True streams the device-staged pack a second
         time and reports steady-state throughput from that pass; the
         one-shot compile(or cache-hit)+run cost stays in
-        ``first_call_seconds`` either way."""
-        t_start = time.time()
+        ``first_call_seconds`` either way.
+
+        The first pass's host phases come back as ``pack_seconds``,
+        ``stage_seconds`` (host-to-device puts and chunk enqueues),
+        ``device_wait_seconds`` and ``results_seconds``, each also a
+        ``simnet.*`` span on the profiler's timeline."""
+        t_start = time.perf_counter()
         cache_before = self.cache.counters()
-        packed = pack_workloads(
-            trace_arrays_list, n_lanes, cfgs if cfgs is not None else self.sim_cfg,
-            pad_to=chunk,
+        # host seconds of each phase of the first pass, one span each on
+        # the profiler's timeline; the timeit re-run adds nothing to them
+        phases = dict.fromkeys(
+            ("pack_seconds", "stage_seconds", "device_wait_seconds", "results_seconds"), 0.0
         )
-        if packed.cfg.ctx_len > self.sim_cfg.ctx_len:
-            raise ValueError(
-                f"packed ctx_len {packed.cfg.ctx_len} exceeds engine ctx_len "
-                f"{self.sim_cfg.ctx_len} (the predictor input width is fixed)"
+        with span("simnet.pack", phases, "pack_seconds"):
+            packed = pack_workloads(
+                trace_arrays_list, n_lanes, cfgs if cfgs is not None else self.sim_cfg,
+                pad_to=chunk,
             )
-        n_live = packed.n_lanes
-        n_lanes = lane_bucket(n_live)
-        if self.mesh is not None:  # every device holds an equal lane slice
-            per = int(np.prod([self.mesh.shape[a] for a in _lane_axes(self.mesh)]))
-            n_lanes = -(-n_lanes // per) * per
-        packed = pad_packed_lanes(packed, n_lanes)
-        self._stage_params()
-        exe = self.executable(packed.n_lanes, chunk)
+            if packed.cfg.ctx_len > self.sim_cfg.ctx_len:
+                raise ValueError(
+                    f"packed ctx_len {packed.cfg.ctx_len} exceeds engine ctx_len "
+                    f"{self.sim_cfg.ctx_len} (the predictor input width is fixed)"
+                )
+            n_live = packed.n_lanes
+            n_lanes = lane_bucket(n_live)
+            if self.mesh is not None:  # every device holds an equal lane slice
+                per = int(np.prod([self.mesh.shape[a] for a in _lane_axes(self.mesh)]))
+                n_lanes = -(-n_lanes // per) * per
+            packed = pad_packed_lanes(packed, n_lanes)
+        with span("simnet.executable"):
+            self._stage_params()
+            exe = self.executable(packed.n_lanes, chunk)
 
         # per-lane configs go device-side once; trace chunks stream through
         # one staged buffer at a time (device memory stays O(chunk)) —
@@ -294,41 +307,48 @@ class SimNetEngine:
                     for k, v in packed.xs.items()}
 
         offsets = range(0, packed.n_steps, chunk)
-        staged = [stage(lo) for lo in offsets] if timeit else None
-        rw = put(np.asarray(packed.retire_width), lane_sh)
-        lc = put(np.asarray(packed.lane_ctx), lane_sh)
+        with span("simnet.stage", phases, "stage_seconds"):
+            staged = [stage(lo) for lo in offsets] if timeit else None
+            rw = put(np.asarray(packed.retire_width), lane_sh)
+            lc = put(np.asarray(packed.lane_ctx), lane_sh)
 
-        def one_pass():
-            t0 = time.time()
-            state = init_state(packed.n_lanes, self.sim_cfg)
-            if st_sh is not None:
-                state = jax.device_put(state, st_sh)
-            for xs in staged if staged is not None else (stage(lo) for lo in offsets):
-                state = exe(self.params, state, xs, rw, lc)
-            lane_total, cycles, overflow = workload_totals(state, packed)
-            jax.block_until_ready(cycles)
-            return time.time() - t0, lane_total, cycles, overflow
+        def one_pass(into):
+            t0 = time.perf_counter()
+            # the puts and the chunk enqueues: the host returns before the
+            # device has run them
+            with span("simnet.stage", into, "stage_seconds"):
+                state = init_state(packed.n_lanes, self.sim_cfg)
+                if st_sh is not None:
+                    state = jax.device_put(state, st_sh)
+                for xs in staged if staged is not None else (stage(lo) for lo in offsets):
+                    state = exe(self.params, state, xs, rw, lc)
+            with span("simnet.device_wait", into, "device_wait_seconds"):
+                lane_total, cycles, overflow = workload_totals(state, packed)
+                jax.block_until_ready(cycles)
+            return time.perf_counter() - t0, lane_total, cycles, overflow
 
-        dt, lane_total, cycles, overflow = one_pass()
-        first_dt = time.time() - t_start  # compile/cache-hit + staging + run
+        dt, lane_total, cycles, overflow = one_pass(phases)
+        first_dt = time.perf_counter() - t_start  # compile/cache-hit + staging + run
         if timeit:
-            dt, lane_total, cycles, overflow = one_pass()
-        cycles = np.asarray(cycles, np.float64)
-        # Numeric guard: a NaN/Inf anywhere in the predictor's latency
-        # stream propagates into these per-workload sums — catch it here,
-        # at the batch boundary, before it can poison aggregated CPI.
-        # (The chaos "batch.numeric" corrupt trigger poisons the totals
-        # directly, flushing this exact path.)
-        cycles = faults.fire("batch.numeric", payload=cycles)
-        finite = np.isfinite(cycles)
-        if not finite.all():
-            raise NumericError(np.flatnonzero(~finite), cycles)
+            dt, lane_total, cycles, overflow = one_pass(None)
+        with span("simnet.results", phases, "results_seconds"):
+            cycles = np.asarray(cycles, np.float64)
+            # Numeric guard: a NaN/Inf anywhere in the predictor's latency
+            # stream propagates into these per-workload sums — catch it here,
+            # at the batch boundary, before it can poison aggregated CPI.
+            # (The chaos "batch.numeric" corrupt trigger poisons the totals
+            # directly, flushing this exact path.)
+            cycles = faults.fire("batch.numeric", payload=cycles)
+            finite = np.isfinite(cycles)
+            if not finite.all():
+                raise NumericError(np.flatnonzero(~finite), cycles)
+            overflow = np.asarray(overflow)
         n_instr = packed.n_instructions
         total_instr = int(n_instr.sum())
         return {
             "workload_cycles": cycles,
             "workload_cpi": cycles / np.maximum(n_instr, 1),
-            "workload_overflow": np.asarray(overflow),
+            "workload_overflow": overflow,
             "n_instructions": n_instr,
             "total_cycles": float(cycles.sum()),
             "total_instructions": total_instr,
@@ -340,6 +360,7 @@ class SimNetEngine:
             "seconds": dt,
             "first_call_seconds": first_dt,
             "cache": self.cache.delta_since(cache_before),
+            **phases,
         }
 
     # -- single-workload convenience (same packed scan underneath) -----

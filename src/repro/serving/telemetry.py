@@ -4,7 +4,7 @@ The paper's throughput story only survives deployment if the service can
 be *run hot* — NeuroScalar's "simulation in the wild" needs the operator
 to see tail latency, queue pressure and pack density, and to contain a
 bad artifact before it eats the drain loop. This module is that layer,
-stdlib-only:
+stdlib-only except `span`, which imports JAX when it is called:
 
 - `Histogram` — fixed-bucket counters with *lock-free reads*: writers
   serialize on a tiny per-histogram mutex (exact counts under threaded
@@ -21,6 +21,10 @@ stdlib-only:
 - structured logs — one JSON object per event on the ``repro.serving``
   logger, every job tagged with a correlation id minted at submit, so a
   request can be followed submit → dispatch → completion across threads.
+- `span` — a named host span on the profiler's timeline (on the same
+  clock as the device's ops when a profile is recorded) whose duration
+  also adds into a per-batch counter; always on, a few microseconds each
+  with no profiler attached.
 
 `Telemetry` bundles the service's standard histograms (queue wait,
 end-to-end latency, queue depth at admission, jobs per batch); the whole
@@ -29,13 +33,14 @@ snapshot rides ``SimServe.stats()`` and the HTTP ``/v1/stats`` endpoint.
 from __future__ import annotations
 
 import bisect
+import contextlib
 import json
 import logging
 import math
 import threading
 import time
 import uuid
-from typing import Any, Dict, Optional, Sequence
+from typing import Any, Dict, Iterator, Optional, Sequence
 
 LOG = logging.getLogger("repro.serving")
 
@@ -62,6 +67,28 @@ def log_event(event: str, *, level: int = logging.DEBUG, **fields) -> None:
     if LOG.isEnabledFor(level):
         LOG.log(level, json.dumps({"event": event, **fields},
                                   default=str, sort_keys=True))
+
+
+@contextlib.contextmanager
+def span(name: str, into: Optional[Dict[str, float]] = None, key: Optional[str] = None,
+         **meta) -> Iterator[Any]:
+    """Time the body as the host span ``name``.
+
+    The span is a `jax.profiler.TraceAnnotation` carrying ``meta``, so a
+    recorded profile shows it on the host timeline beside the device's
+    ops; the annotation is yielded, and ``set_metadata`` adds fields that
+    are known only inside the body. When ``into`` is given, the body's
+    `time.perf_counter` seconds add into ``into[key]``, also when it
+    raises."""
+    import jax
+
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation(name, **meta) as ann:
+        try:
+            yield ann
+        finally:
+            if into is not None:
+                into[key] = into.get(key, 0.0) + time.perf_counter() - t0
 
 
 class Histogram:
