@@ -218,14 +218,18 @@ def populated_ring_state(slices, pcfg, n_lanes=256, steps=100):
     import jax.numpy as jnp
 
     from repro.core import features as F
-    from repro.core.simulator import SimConfig, init_state, make_sim_scan, pack_workloads
+    from repro.core.simulator import (
+        SimConfig, chunk_time_major, init_state, make_sim_scan, pack_workloads,
+    )
 
     cfg = SimConfig(ctx_len=pcfg.ctx_len)
     packed = pack_workloads([F.trace_arrays(t) for t in slices[:n_lanes]], 1, cfg)
-    xs = {k: jnp.asarray(v[:steps]) for k, v in packed.xs.items()}
+    lanes = {k: v[0] for k, v in packed.xs.items()}  # the one chunk, (L, T, ...)
+    xs = {k: jnp.asarray(v[:, :steps]) for k, v in lanes.items()}
     step = make_sim_scan(None, cfg, emit_outputs=False)
-    state, _ = jax.jit(lambda s, x: jax.lax.scan(step, s, x))(init_state(n_lanes, cfg), xs)
-    return cfg, state, jnp.asarray(packed.xs["feat"][steps]), jnp.asarray(packed.xs["addr"][steps])
+    state, _ = jax.jit(lambda s, x: jax.lax.scan(step, s, chunk_time_major(x)))(
+        init_state(n_lanes, cfg), xs)
+    return cfg, state, jnp.asarray(lanes["feat"][:, steps]), jnp.asarray(lanes["addr"][:, steps])
 
 
 def kernels_vs_oracle(slices, params, pcfg):
@@ -315,11 +319,11 @@ def lane_state_shards(sn, slices, mesh):
     from repro.serving.simnet_engine import chunk_shardings, lane_sharding, state_shardings
 
     eng = sn.engine
-    packed = pack_workloads([F.trace_arrays(t) for t in slices], 1, eng.sim_cfg)
+    packed = pack_workloads([F.trace_arrays(t) for t in slices], 1, eng.sim_cfg, chunk=CHUNK)
     L = packed.n_lanes
     xs_sh, lane_sh = chunk_shardings(mesh), lane_sharding(mesh)
     state = jax.device_put(init_state(L, eng.sim_cfg), state_shardings(mesh))
-    xs = {k: jax.device_put(v[:CHUNK], xs_sh[k]) for k, v in packed.xs.items()}
+    xs = {k: jax.device_put(v[0], xs_sh[k]) for k, v in packed.xs.items()}
     out = eng.executable(L, CHUNK)(
         eng.params, state, xs,
         jax.device_put(packed.retire_width, lane_sh),

@@ -42,7 +42,8 @@ of one `segment_sum` over the lane axis.
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, NamedTuple, Optional, Sequence, Union
+import math
+from typing import Callable, Dict, NamedTuple, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
@@ -527,9 +528,13 @@ def simulate_trace(trace_arrays: dict, predict_fn, cfg: SimConfig, n_lanes: int)
 class PackedWorkloads:
     """Lanes from many (workload, SimConfig) jobs packed on one lane axis.
 
-    ``xs`` is time-major numpy: feat (T, L, 41), addr (T, L, 5), is_store
-    (T, L), labels (T, L, 3), active (T, L) bool. Rows past a lane's own
-    sub-trace length are zero-filled and inactive (ragged-length masking).
+    ``xs`` is numpy, lane-major per chunk: feat (n_chunks, L, chunk, 41),
+    addr (n_chunks, L, chunk, 5), is_store (n_chunks, L, chunk), labels
+    (n_chunks, L, chunk, 3), active (n_chunks, L, chunk) bool, so chunk c
+    of a lane is one contiguous block and ``xs[k][c]`` one contiguous put.
+    `chunk_time_major` turns a chunk into the (chunk, L, ...) the scan
+    steps over. Steps past a lane's own sub-trace length are zero-filled
+    and inactive (ragged-length masking).
     """
 
     xs: dict
@@ -550,29 +555,89 @@ class PackedWorkloads:
         return int(self.n_instructions.shape[0])
 
     @property
-    def n_steps(self) -> int:
+    def n_chunks(self) -> int:
         return int(self.xs["feat"].shape[0])
+
+    @property
+    def chunk(self) -> int:
+        return int(self.xs["feat"].shape[2])
+
+    @property
+    def n_steps(self) -> int:
+        return self.n_chunks * self.chunk
+
+
+class PackBuffer:
+    """Host memory a pack is written into: one flat array per key, grown to
+    the largest pack seen and viewed at each pack's shape (a prefix of the
+    flat array). A caller that packs again and again keeps one, so the
+    pages are faulted in once rather than on every pack; it must not pack
+    into it again while a put from the previous pack may still read it.
+    ``reuses`` / ``allocations`` count the packs that fitted / grew it."""
+
+    def __init__(self):
+        self._flat: Dict[str, np.ndarray] = {}
+        self.reuses = 0
+        self.allocations = 0
+
+    def counters(self) -> Dict[str, int]:
+        return {"reuses": self.reuses, "allocations": self.allocations,
+                "bytes": sum(a.nbytes for a in self._flat.values())}
+
+    def views(self, shapes: Dict[str, tuple]) -> Dict[str, np.ndarray]:
+        """{key: (shape, dtype)} -> {key: array of that shape}, contents
+        left as they were: the caller writes every element."""
+        grew = False
+        out = {}
+        for k, (shape, dtype) in shapes.items():
+            n = math.prod(shape)
+            flat = self._flat.get(k)
+            if flat is None or flat.size < n:
+                flat = self._flat[k] = np.empty(n, dtype)
+                grew = True
+            out[k] = flat[:n].reshape(shape)
+        if grew:
+            self.allocations += 1
+        else:
+            self.reuses += 1
+        return out
+
+
+def lane_counts(n_workloads: int, n_lanes: Union[int, Sequence[int]]) -> list:
+    """Lanes per job: one count for every job, or one count each."""
+    lanes = [n_lanes] * n_workloads if isinstance(n_lanes, int) else list(n_lanes)
+    if len(lanes) != n_workloads:
+        raise ValueError(f"n_lanes has {len(lanes)} entries for {n_workloads} workloads")
+    return lanes
 
 
 def pack_workloads(
     trace_arrays_list: Sequence[dict],
     n_lanes: Union[int, Sequence[int]] = 8,
     cfg: Union[SimConfig, Sequence[SimConfig], None] = None,
-    pad_to: int = 1,
+    chunk: Optional[int] = None,
+    total_lanes: Optional[int] = None,
+    buffer: Optional[PackBuffer] = None,
 ) -> PackedWorkloads:
     """Pack W workloads (each a `trace_arrays` dict) into one lane batch.
 
     n_lanes / cfg may be per-workload sequences; the packed scan runs with
     ctx_len = max over jobs, and per-lane retire_width / lane_ctx replay
-    each job's own SimConfig exactly. ``pad_to`` rounds the time axis up
-    (with inactive steps) so chunked streaming never needs a ragged tail.
+    each job's own SimConfig exactly. Job w's lane l is rows
+    [l*p, (l+1)*p) of its own arrays, copied chunk by chunk as contiguous
+    blocks. ``chunk`` cuts the time axis (None: one chunk as long as the
+    longest lane); the last chunk is padded with inactive steps.
+    ``total_lanes`` pads the lane axis with dead lanes (executable
+    bucketing): inactive at every step, they freeze in their all-zero
+    initial state (cur_tick 0, no in-flight entries, drain 0, overflow 0)
+    and add exactly nothing to any workload's segment_sum. The pack is
+    written into ``buffer`` (a fresh one by default), every element of
+    it, so nothing of an earlier pack in a reused buffer survives.
     """
     W = len(trace_arrays_list)
     if W == 0:
         raise ValueError("pack_workloads needs at least one workload")
-    lanes = [n_lanes] * W if isinstance(n_lanes, int) else list(n_lanes)
-    if len(lanes) != W:
-        raise ValueError(f"n_lanes has {len(lanes)} entries for {W} workloads")
+    lanes = lane_counts(W, n_lanes)
     if cfg is None:
         cfgs = [SimConfig()] * W
     elif isinstance(cfg, SimConfig):
@@ -598,22 +663,27 @@ def pack_workloads(
         if T < ln:
             raise ValueError(f"workload of {T} instructions cannot fill {ln} lanes")
         per.append(T // ln)
-    T_max = max(per)
-    T_max = ((T_max + pad_to - 1) // pad_to) * pad_to
-    L = sum(lanes)
+    chunk = chunk or max(per)
+    n_chunks = -(-max(per) // chunk)
+    n_live = sum(lanes)
+    L = n_live if total_lanes is None else total_lanes
+    if L < n_live:
+        raise ValueError(f"cannot pack {n_live} lanes into {L}")
     Q = max(c.ctx_len for c in cfgs)
     ucfg = dataclasses.replace(cfgs[0], ctx_len=Q)
 
-    xs = {
-        "feat": np.zeros((T_max, L, F.STATIC_END), np.float32),
-        "addr": np.zeros((T_max, L, F.N_ADDR_KEYS), np.int32),
-        "is_store": np.zeros((T_max, L), bool),
-        "labels": np.zeros((T_max, L, 3), np.float32),
-        "active": np.zeros((T_max, L), bool),
-    }
+    lead = (n_chunks, L, chunk)
+    xs = (buffer or PackBuffer()).views({
+        "feat": (lead + (F.STATIC_END,), np.float32),
+        "addr": (lead + (F.N_ADDR_KEYS,), np.int32),
+        "is_store": (lead, bool),
+        "labels": (lead + (3,), np.float32),
+        "active": (lead, bool),
+    })
+    # dead lanes: id 0 is safe, their totals are exactly zero
     workload_id = np.zeros(L, np.int32)
-    retire_width = np.zeros(L, np.int32)
-    lane_ctx = np.zeros(L, np.int32)
+    retire_width = np.ones(L, np.int32)
+    lane_ctx = np.full(L, Q, np.int32)
     lane_steps = np.zeros(L, np.int64)
     n_instructions = np.zeros(W, np.int64)
 
@@ -621,16 +691,27 @@ def pack_workloads(
     for w, (arrs, ln, c, p) in enumerate(zip(trace_arrays_list, lanes, cfgs, per)):
         hi = lo + ln
         used = p * ln
+        rows = {}
         for k in ("feat", "addr", "is_store", "labels"):
-            a = np.asarray(arrs[k])[:used]
-            xs[k][:p, lo:hi] = np.swapaxes(a.reshape(ln, p, *a.shape[1:]), 0, 1)
-        xs["active"][:p, lo:hi] = True
+            a = np.asarray(arrs[k])
+            rows[k] = a[:used].reshape(ln, p, *a.shape[1:])
+        for ci in range(n_chunks):
+            t0 = ci * chunk
+            n = min(chunk, max(p - t0, 0))  # the lanes' live steps in it
+            for k, v in rows.items():
+                xs[k][ci, lo:hi, :n] = v[:, t0 : t0 + n]
+            xs["active"][ci, lo:hi, :n] = True
+            if n < chunk:  # the ragged tail
+                for v in xs.values():
+                    v[ci, lo:hi, n:] = 0
         workload_id[lo:hi] = w
         retire_width[lo:hi] = c.retire_width
         lane_ctx[lo:hi] = c.ctx_len
         lane_steps[lo:hi] = p
         n_instructions[w] = used
         lo = hi
+    for v in xs.values():
+        v[:, lo:] = 0
 
     uniform = all(
         c.retire_width == cfgs[0].retire_width and c.ctx_len == Q for c in cfgs
@@ -642,47 +723,21 @@ def pack_workloads(
     )
 
 
-def pad_packed_lanes(packed: PackedWorkloads, n_lanes: int) -> PackedWorkloads:
-    """Grow a pack's lane axis to ``n_lanes`` with dead lanes (executable
-    bucketing). Dead lanes are inactive at every step, so they freeze in
-    their all-zero initial state: cur_tick 0, no in-flight entries, drain
-    0, overflow 0 — they contribute exactly nothing to any workload's
-    segment_sum and per-workload totals stay bit-identical."""
-    L = packed.n_lanes
-    if n_lanes < L:
-        raise ValueError(f"cannot shrink a {L}-lane pack to {n_lanes} lanes")
-    if n_lanes == L:
-        return packed
-    pad = n_lanes - L
-    xs = {
-        k: np.concatenate(
-            [v, np.zeros((v.shape[0], pad) + v.shape[2:], v.dtype)], axis=1
-        )
-        for k, v in packed.xs.items()
-    }
-
-    def lane_pad(a, fill):
-        return np.concatenate([a, np.full(pad, fill, a.dtype)])
-
-    return dataclasses.replace(
-        packed,
-        xs=xs,
-        # id 0 is safe: a dead lane's totals are exactly zero
-        workload_id=lane_pad(packed.workload_id, 0),
-        retire_width=lane_pad(packed.retire_width, 1),
-        lane_ctx=lane_pad(packed.lane_ctx, packed.cfg.ctx_len),
-        lane_steps=lane_pad(packed.lane_steps, 0),
-    )
+def chunk_time_major(xs: dict) -> dict:
+    """One chunk of a pack, (L, chunk, ...) per key, to the time-major
+    (chunk, L, ...) that `lax.scan` steps over. Under a lane mesh each
+    device swaps its own lane slice: no communication."""
+    with jax.named_scope("layout"):
+        return {k: jnp.swapaxes(v, 0, 1) for k, v in xs.items()}
 
 
 def max_packed_steps(
     trace_arrays_list: Sequence[dict], n_lanes: Union[int, Sequence[int]]
 ) -> int:
     """Longest per-lane sub-trace over a prospective pack (= the packed time
-    axis before pad_to rounding). The session uses this to shrink the
+    axis before chunk rounding). The session uses this to shrink the
     streaming chunk for small packs so padding stays negligible."""
-    W = len(trace_arrays_list)
-    lanes = [n_lanes] * W if isinstance(n_lanes, int) else list(n_lanes)
+    lanes = lane_counts(len(trace_arrays_list), n_lanes)
     return max(
         int(a["feat"].shape[0]) // ln for a, ln in zip(trace_arrays_list, lanes)
     )
@@ -715,7 +770,7 @@ def simulate_many(
     step = make_sim_scan(
         predict_fn, packed.cfg, retire_width=rw, lane_ctx=lc, emit_outputs=False
     )
-    xs = {k: jnp.asarray(v) for k, v in packed.xs.items()}
+    xs = chunk_time_major({k: jnp.asarray(v[0]) for k, v in packed.xs.items()})
     state = init_state(packed.n_lanes, packed.cfg)
     state, _ = jax.lax.scan(step, state, xs)
     lane_total, cycles, overflow = workload_totals(state, packed)

@@ -17,7 +17,7 @@ Two mechanisms fix that:
    never collide in the cache.
 2. **Bucketing.** Lane counts round up to power-of-two buckets (dead
    lanes ride along fully masked via the ``active`` input, so totals are
-   bit-identical — see `pad_packed_lanes`), and the streaming chunk
+   bit-identical — see `pack_workloads`' ``total_lanes``), and the streaming chunk
    rounds to a power of two capped at the configured maximum. A
    heterogeneous request mix therefore lands on a handful of executable
    shapes instead of one per (model × lane count × trace length).

@@ -164,3 +164,14 @@ class ModelRegistry:
     def ids(self) -> Iterable[str]:
         with self._lock:
             return tuple(self._engines)
+
+    def pack_buffer_counters(self) -> Dict[str, int]:
+        """The resident engines' host pack buffers, summed: packs that
+        reused one, packs that grew one, and the bytes they hold."""
+        with self._lock:
+            engines = {id(e): e for e in self._engines.values()}.values()
+        total = dict.fromkeys(("reuses", "allocations", "bytes"), 0)
+        for e in engines:
+            for k, v in e.pack_buffer_counters.items():
+                total[k] += v
+        return total

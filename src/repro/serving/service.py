@@ -124,6 +124,9 @@ class BatchReport:
     first_call_seconds: float
     throughput_ips: float
     cache: Dict[str, Any]  # hit/miss/compile-seconds delta of this batch
+    # the engine's host pack buffer: reuses/allocations in this batch and
+    # the bytes it holds
+    pack_buffer: Dict[str, int] = dataclasses.field(default_factory=dict)
     # host seconds of each phase, timed by its `telemetry.span`: the jobs'
     # featurizing at submit, then the engine's first pass
     featurize_seconds: float = 0.0
@@ -799,6 +802,7 @@ class SimServe:
                 first_call_seconds=float(res["first_call_seconds"]),
                 throughput_ips=float(res["throughput_ips"]),
                 cache=dict(res["cache"]),
+                pack_buffer=dict(res["pack_buffer"]),
                 featurize_seconds=sum(j.featurize_seconds for j in jobs),
                 pack_seconds=float(res["pack_seconds"]),
                 stage_seconds=float(res["stage_seconds"]),
@@ -956,6 +960,7 @@ class SimServe:
             "telemetry": self.telemetry.snapshot(),
             "breakers": self.registry.breaker_snapshots(),
             "cache": self.cache.stats(),
+            "pack_buffer": self.registry.pack_buffer_counters(),
             "faults": faults.snapshot(),  # None unless a chaos plan is live
         })
         return snap

@@ -26,6 +26,7 @@ production mesh alongside the LM pool (simnet-c3 / simnet-rb7 arch cells).
 """
 from __future__ import annotations
 
+import threading
 import time
 from typing import Dict, Optional, Sequence, Union
 
@@ -42,12 +43,14 @@ from repro.core.predictor import (
     make_fused_predict_fn,
 )
 from repro.core.simulator import (
+    PackBuffer,
     SimConfig,
     SimState,
+    chunk_time_major,
     init_state,
+    lane_counts,
     make_sim_scan,
     pack_workloads,
-    pad_packed_lanes,
     workload_totals,
 )
 from repro.serving import faults
@@ -95,13 +98,14 @@ def state_shardings(mesh):
 
 
 def chunk_specs(n_lanes: int, chunk: int):
-    """ShapeDtypeStructs for one scan chunk of packed trace input."""
+    """ShapeDtypeStructs for one chunk of packed trace input, lane-major
+    as the pack lays it out (`run_chunk` swaps it to time-major)."""
     return {
-        "feat": jax.ShapeDtypeStruct((chunk, n_lanes, F.STATIC_END), jnp.float32),
-        "addr": jax.ShapeDtypeStruct((chunk, n_lanes, F.N_ADDR_KEYS), jnp.int32),
-        "is_store": jax.ShapeDtypeStruct((chunk, n_lanes), jnp.bool_),
-        "labels": jax.ShapeDtypeStruct((chunk, n_lanes, 3), jnp.float32),
-        "active": jax.ShapeDtypeStruct((chunk, n_lanes), jnp.bool_),
+        "feat": jax.ShapeDtypeStruct((n_lanes, chunk, F.STATIC_END), jnp.float32),
+        "addr": jax.ShapeDtypeStruct((n_lanes, chunk, F.N_ADDR_KEYS), jnp.int32),
+        "is_store": jax.ShapeDtypeStruct((n_lanes, chunk), jnp.bool_),
+        "labels": jax.ShapeDtypeStruct((n_lanes, chunk, 3), jnp.float32),
+        "active": jax.ShapeDtypeStruct((n_lanes, chunk), jnp.bool_),
     }
 
 
@@ -114,9 +118,9 @@ def lane_param_specs(n_lanes: int):
 
 
 def chunk_shardings(mesh):
-    lanes_axes = _lane_axes(mesh)
-    spec = P(None, lanes_axes if len(lanes_axes) > 1 else lanes_axes[0])
-    s = NamedSharding(mesh, spec)
+    """A chunk's lane axis (axis 0) over the mesh: each device's slice is
+    one contiguous block of the host's pack."""
+    s = lane_sharding(mesh)
     return {"feat": s, "addr": s, "is_store": s, "labels": s, "active": s}
 
 
@@ -141,6 +145,14 @@ class SimNetEngine:
         self.cache = cache if cache is not None else global_cache()
         self.params = params
         self._params_staged = params is None  # nothing to stage teacher-forced
+        # host memory every pack is written into, kept from call to call so
+        # its pages are faulted in once; the lock keeps a second caller from
+        # rewriting it while this call's asynchronous puts may still read it
+        self._pack_lock = threading.Lock()
+        self._pack_buffer = PackBuffer()  # guarded-by: _pack_lock
+        # its reuse/allocation counts and bytes, replaced whole after each
+        # pack so that readers need no lock
+        self.pack_buffer_counters = self._pack_buffer.counters()
 
         # repro-lint: scan-reachable — the jitted per-chunk body
         def run_chunk(p, state: SimState, xs, retire_width, lane_ctx):
@@ -166,7 +178,7 @@ class SimNetEngine:
                 retire_width=retire_width, lane_ctx=lane_ctx, emit_outputs=False,
                 predict_state_fn=predict_state,
             )
-            state, _ = jax.lax.scan(step, state, xs)
+            state, _ = jax.lax.scan(step, state, chunk_time_major(xs))
             return state
 
         if mesh is not None:
@@ -260,7 +272,13 @@ class SimNetEngine:
         The first pass's host phases come back as ``pack_seconds``,
         ``stage_seconds`` (host-to-device puts and chunk enqueues),
         ``device_wait_seconds`` and ``results_seconds``, each also a
-        ``simnet.*`` span on the profiler's timeline."""
+        ``simnet.*`` span on the profiler's timeline; ``pack_buffer`` holds
+        the host pack buffer's reuses and allocations in this call and the
+        bytes it holds."""
+        with self._pack_lock:
+            return self._simulate_many(trace_arrays_list, n_lanes, chunk, cfgs, timeit)
+
+    def _simulate_many(self, trace_arrays_list, n_lanes, chunk, cfgs, timeit) -> dict:
         t_start = time.perf_counter()
         cache_before = self.cache.counters()
         # host seconds of each phase of the first pass, one span each on
@@ -269,21 +287,22 @@ class SimNetEngine:
             ("pack_seconds", "stage_seconds", "device_wait_seconds", "results_seconds"), 0.0
         )
         with span("simnet.pack", phases, "pack_seconds"):
+            n_live = sum(lane_counts(len(trace_arrays_list), n_lanes))
+            n_bucket = lane_bucket(n_live)
+            if self.mesh is not None:  # every device holds an equal lane slice
+                per = int(np.prod([self.mesh.shape[a] for a in _lane_axes(self.mesh)]))
+                n_bucket = -(-n_bucket // per) * per
+            buffer_before = self._pack_buffer.counters()
             packed = pack_workloads(
                 trace_arrays_list, n_lanes, cfgs if cfgs is not None else self.sim_cfg,
-                pad_to=chunk,
+                chunk=chunk, total_lanes=n_bucket, buffer=self._pack_buffer,
             )
+            self.pack_buffer_counters = self._pack_buffer.counters()
             if packed.cfg.ctx_len > self.sim_cfg.ctx_len:
                 raise ValueError(
                     f"packed ctx_len {packed.cfg.ctx_len} exceeds engine ctx_len "
                     f"{self.sim_cfg.ctx_len} (the predictor input width is fixed)"
                 )
-            n_live = packed.n_lanes
-            n_lanes = lane_bucket(n_live)
-            if self.mesh is not None:  # every device holds an equal lane slice
-                per = int(np.prod([self.mesh.shape[a] for a in _lane_axes(self.mesh)]))
-                n_lanes = -(-n_lanes // per) * per
-            packed = pad_packed_lanes(packed, n_lanes)
         with span("simnet.executable"):
             self._stage_params()
             exe = self.executable(packed.n_lanes, chunk)
@@ -302,13 +321,13 @@ class SimNetEngine:
         lane_sh = lane_sharding(self.mesh) if self.mesh is not None else None
         st_sh = state_shardings(self.mesh) if self.mesh is not None else None
 
-        def stage(lo):
-            return {k: put(v[lo : lo + chunk], xs_sh[k] if xs_sh else None)
+        def stage(c):
+            return {k: put(v[c], xs_sh[k] if xs_sh else None)
                     for k, v in packed.xs.items()}
 
-        offsets = range(0, packed.n_steps, chunk)
+        chunks = range(packed.n_chunks)
         with span("simnet.stage", phases, "stage_seconds"):
-            staged = [stage(lo) for lo in offsets] if timeit else None
+            staged = [stage(c) for c in chunks] if timeit else None
             rw = put(np.asarray(packed.retire_width), lane_sh)
             lc = put(np.asarray(packed.lane_ctx), lane_sh)
 
@@ -320,7 +339,7 @@ class SimNetEngine:
                 state = init_state(packed.n_lanes, self.sim_cfg)
                 if st_sh is not None:
                     state = jax.device_put(state, st_sh)
-                for xs in staged if staged is not None else (stage(lo) for lo in offsets):
+                for xs in staged if staged is not None else (stage(c) for c in chunks):
                     state = exe(self.params, state, xs, rw, lc)
             with span("simnet.device_wait", into, "device_wait_seconds"):
                 lane_total, cycles, overflow = workload_totals(state, packed)
@@ -360,6 +379,12 @@ class SimNetEngine:
             "seconds": dt,
             "first_call_seconds": first_dt,
             "cache": self.cache.delta_since(cache_before),
+            "pack_buffer": {
+                "reuses": self.pack_buffer_counters["reuses"] - buffer_before["reuses"],
+                "allocations": (self.pack_buffer_counters["allocations"]
+                                - buffer_before["allocations"]),
+                "bytes": self.pack_buffer_counters["bytes"],
+            },
             **phases,
         }
 

@@ -154,6 +154,24 @@ def test_result_drains_lazily(traces):
     assert w.total_cycles > 0
 
 
+def test_pack_buffer_reused_batch_after_batch(traces):
+    """The engine packs every batch into the host buffer it keeps: the first
+    batch allocates it, a batch of the same shape reuses it, and the
+    counts show on each BatchReport and in stats()."""
+    serve = SimServe(cache=CompileCache())
+    serve.register("tf16", sim_cfg=SimConfig(ctx_len=16))
+    reports = []
+    for _ in range(2):
+        for tr in traces:
+            serve.submit(tr, "tf16", n_lanes=2)
+        reports += serve.drain()
+    assert [r.pack_buffer["allocations"] for r in reports] == [1, 0]
+    assert [r.pack_buffer["reuses"] for r in reports] == [0, 1]
+    held = reports[0].pack_buffer["bytes"]
+    assert held > 0 and reports[1].pack_buffer["bytes"] == held
+    assert serve.stats()["pack_buffer"] == {"reuses": 1, "allocations": 1, "bytes": held}
+
+
 def test_incompatible_sim_cfg_rejected_at_submit(traces):
     """SimConfig fields the pack cannot replay per lane (max_latency here)
     are baked into the resident executable — a mismatching job must fail

@@ -47,21 +47,127 @@ def test_packed_matches_separate_exact(arrs):
         assert int(many["workload_overflow"][i]) == int(ref["overflow"])
 
 
+def _lane_rows(a):
+    """A pack key, (n_chunks, L, chunk, ...), as each lane's whole time
+    axis: (L, n_chunks * chunk, ...)."""
+    return np.concatenate(list(a), axis=1)
+
+
+def _old_time_major_pack(arrs, lanes, cfgs, pad_to, total_lanes):
+    """The time-major (T, L, ...) pack as it was built before the lane-major
+    layout: zeros, each job's lanes written as one column block, dead lanes
+    appended."""
+    per = [a["feat"].shape[0] // ln for a, ln in zip(arrs, lanes)]
+    T = -(-max(per) // pad_to) * pad_to
+    L = sum(lanes)
+    xs = {
+        "feat": np.zeros((T, total_lanes, F.STATIC_END), np.float32),
+        "addr": np.zeros((T, total_lanes, F.N_ADDR_KEYS), np.int32),
+        "is_store": np.zeros((T, total_lanes), bool),
+        "labels": np.zeros((T, total_lanes, 3), np.float32),
+        "active": np.zeros((T, total_lanes), bool),
+    }
+    lane = {"workload_id": np.zeros(total_lanes, np.int32),
+            "retire_width": np.ones(total_lanes, np.int32),
+            "lane_ctx": np.full(total_lanes, max(c.ctx_len for c in cfgs), np.int32),
+            "lane_steps": np.zeros(total_lanes, np.int64)}
+    lo = 0
+    for w, (a, ln, c, p) in enumerate(zip(arrs, lanes, cfgs, per)):
+        for k in ("feat", "addr", "is_store", "labels"):
+            v = np.asarray(a[k])[: p * ln]
+            xs[k][:p, lo:lo + ln] = np.swapaxes(v.reshape(ln, p, *v.shape[1:]), 0, 1)
+        xs["active"][:p, lo:lo + ln] = True
+        for k, v in (("workload_id", w), ("retire_width", c.retire_width),
+                     ("lane_ctx", c.ctx_len), ("lane_steps", p)):
+            lane[k][lo:lo + ln] = v
+        lo += ln
+    assert lo == L
+    return xs, lane
+
+
+@pytest.mark.parametrize("chunk,total_lanes", [(256, None), (256, 32), (100, 19), (None, None)])
+def test_lane_major_pack_matches_time_major(arrs, chunk, total_lanes):
+    """Ragged jobs, mixed SimConfigs and 18 live lanes (not a power of two):
+    the lane-major pack swapped back to time-major equals the old
+    time-major pack element for element, dead lanes included."""
+    lanes = [4, 2, 8, 4]
+    cfgs = [SimConfig(ctx_len=16, retire_width=2), SimConfig(ctx_len=32),
+            SimConfig(ctx_len=8, retire_width=4), SimConfig(ctx_len=32, retire_width=1)]
+    packed = pack_workloads(arrs, lanes, cfgs, chunk=chunk, total_lanes=total_lanes)
+    per = [a["feat"].shape[0] // ln for a, ln in zip(arrs, lanes)]
+    L = total_lanes or sum(lanes)
+    want, lane = _old_time_major_pack(arrs, lanes, cfgs, chunk or max(per), L)
+    assert packed.n_lanes == L
+    for k, v in want.items():
+        got = packed.xs[k]
+        assert got.shape[:3] == (packed.n_chunks, L, packed.chunk)
+        time_major = np.swapaxes(got, 1, 2).reshape((packed.n_steps, L) + got.shape[3:])
+        assert time_major.dtype == v.dtype
+        np.testing.assert_array_equal(time_major, v, err_msg=k)
+    for k, v in lane.items():
+        np.testing.assert_array_equal(getattr(packed, k), v, err_msg=k)
+
+
 def test_ragged_lengths_masked(arrs):
     """Lanes from shorter workloads freeze once their sub-trace ends; the
-    packed time axis is max(per-lane length) rounded up to pad_to."""
-    packed = pack_workloads(arrs, n_lanes=4, cfg=SimConfig(ctx_len=16), pad_to=256)
+    packed time axis is max(per-lane length) rounded up to the chunk."""
+    packed = pack_workloads(arrs, n_lanes=4, cfg=SimConfig(ctx_len=16), chunk=256)
     per = [a["feat"].shape[0] // 4 for a in arrs]
     assert packed.n_steps == ((max(per) + 255) // 256) * 256
-    active = packed.xs["active"]
+    assert packed.chunk == 256
+    active = _lane_rows(packed.xs["active"])  # (L, T)
+    labels = _lane_rows(packed.xs["labels"])  # (L, T, 3)
     lo = 0
     for w, p in enumerate(per):
-        assert active[:p, lo : lo + 4].all()
-        assert not active[p:, lo : lo + 4].any()
+        assert active[lo : lo + 4, :p].all()
+        assert not active[lo : lo + 4, p:].any()
         assert int(packed.n_instructions[w]) == p * 4
+        # each lane's steps past its own sub-trace are zero-filled
+        assert labels[lo : lo + 4, p:].sum() == 0.0
         lo += 4
     # padded rows are zero-filled
-    assert packed.xs["labels"][max(per):].sum() == 0.0
+    assert labels[:, max(per):].sum() == 0.0
+
+
+def test_engine_reused_pack_buffer_matches_fresh_engine(arrs):
+    """One engine packs a large ragged batch, then a smaller full one, then
+    a third shape, into the host buffer it keeps: every call's totals are
+    bit-identical to a fresh engine's, so no stale row, dead lane or
+    aliased host memory of an earlier call leaks in; a repeated shape
+    reuses the buffer."""
+    import jax
+
+    from repro.core.predictor import PredictorConfig, init_predictor
+    from repro.serving.compile_cache import CompileCache
+    from repro.serving.simnet_engine import SimNetEngine
+
+    pcfg = PredictorConfig(kind="c1", ctx_len=16)
+    params, _ = init_predictor(jax.random.PRNGKey(0), pcfg)
+    cache = CompileCache()
+
+    def engine():
+        return SimNetEngine(params, pcfg, SimConfig(ctx_len=16), cache=cache)
+
+    packs = [
+        (arrs, [4, 2, 8, 4], 256),  # ragged, 18 lanes in a 32-lane bucket
+        ([{k: v[:1024] for k, v in a.items()} for a in arrs[:2]],
+         [4, 4], 128),  # two full chunks, 8 lanes, no dead lane
+        (arrs[1:3], [2, 1], 512),  # a third shape: 3 lanes in a bucket of 4
+    ]
+    eng = engine()
+    for trs, lanes, chunk in packs:
+        got = eng.simulate_many(trs, n_lanes=lanes, chunk=chunk)
+        want = engine().simulate_many(trs, n_lanes=lanes, chunk=chunk)
+        np.testing.assert_array_equal(got["workload_cycles"], want["workload_cycles"])
+        np.testing.assert_array_equal(got["workload_overflow"], want["workload_overflow"])
+    assert got["pack_buffer"]["allocations"] == 0  # the third fits the first's buffer
+    again = eng.simulate_many(*packs[2][:1], n_lanes=packs[2][1], chunk=packs[2][2])
+    assert again["pack_buffer"]["reuses"] == 1
+    assert again["pack_buffer"]["allocations"] == 0
+    np.testing.assert_array_equal(again["workload_cycles"], got["workload_cycles"])
+    assert eng.pack_buffer_counters == {
+        "reuses": 3, "allocations": 1, "bytes": again["pack_buffer"]["bytes"]}
+    assert again["pack_buffer"]["bytes"] > 0
 
 
 def test_heterogeneous_configs_exact(arrs):
@@ -232,3 +338,42 @@ def test_lane_sharded_session_matches_des_and_one_device():
     out = json.loads(res.stdout.strip().splitlines()[-1])
     assert out["devices"] == 4
     assert out["sharded"] == out["des"] == out["single"]
+
+
+def test_engine_pack_buffer_shared_by_threads(arrs):
+    """Threads calling one engine's simulate_many at once share its host
+    pack buffer: each call still returns what it returns alone."""
+    import sys
+    import threading
+
+    from repro.serving.compile_cache import CompileCache
+    from repro.serving.simnet_engine import SimNetEngine
+
+    eng = SimNetEngine(None, None, SimConfig(ctx_len=16), cache=CompileCache())
+    packs = [(arrs, [4, 2, 8, 4]), (arrs[::-1], [2, 8, 4, 4]), (arrs[1:3], [2, 1])]
+    want = [eng.simulate_many(a, n_lanes=ln, chunk=256)["workload_cycles"] for a, ln in packs]
+    got, errors = {}, []
+
+    def worker(i):
+        try:
+            a, ln = packs[i % len(packs)]
+            for r in range(2):
+                got[i, r] = eng.simulate_many(a, n_lanes=ln, chunk=256)["workload_cycles"]
+        except Exception as e:  # reported below, with the thread's index
+            errors.append((i, repr(e)))
+
+    threads = [threading.Thread(target=worker, args=(i,)) for i in range(3 * (os.cpu_count() or 4))]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=240)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors
+    assert len(got) == 2 * len(threads)
+    for (i, _), cycles in got.items():
+        np.testing.assert_array_equal(cycles, want[i % len(packs)])

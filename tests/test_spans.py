@@ -3,8 +3,8 @@ scopes.
 
 A batch's host phases are `telemetry.span`s: each is a `TraceAnnotation`
 on the profiler's timeline and adds its seconds into the batch's
-`BatchReport`. The named scopes (`assembly`, `trunk`, `head`, `retire`)
-are HLO metadata only: the compiled chunk program must be the same
+`BatchReport`. The named scopes (`layout`, `assembly`, `trunk`, `head`,
+`retire`) are HLO metadata only: the compiled chunk program must be the same
 instructions with or without them.
 """
 import contextlib
@@ -139,14 +139,14 @@ def _compiled_run_chunk(kind):
     return eng.lower(64, 16).compile().as_text()
 
 
-@pytest.mark.parametrize("kind,scopes", [("c3", {"assembly", "trunk", "head", "retire"}),
-                                         ("rb7", {"assembly", "trunk", "head", "retire"}),
-                                         ("tf", {"retire"})])
+@pytest.mark.parametrize("kind,scopes", [("c3", {"layout", "assembly", "trunk", "head", "retire"}),
+                                         ("rb7", {"layout", "assembly", "trunk", "head", "retire"}),
+                                         ("tf", {"layout", "retire"})])
 def test_named_scopes_leave_run_chunk_the_same(kind, scopes, monkeypatch):
     scoped = _compiled_run_chunk(kind)
     names = set(re.findall(r'op_name="([^"]*)"', scoped))
     assert {part for n in names for part in n.split("/")} & {
-        "assembly", "trunk", "head", "retire"} == scopes
+        "layout", "assembly", "trunk", "head", "retire"} == scopes
     with monkeypatch.context() as m:
         m.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
         plain = _compiled_run_chunk(kind)

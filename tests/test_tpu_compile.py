@@ -4,8 +4,8 @@ Interpret mode and the CPU backend accept kernels the TPU compiler refuses
 (a sublane-into-lane reshape, an in-kernel dynamic slice, a reverse), so
 these tests lower and compile, for one chip of a `v5e:2x2` topology, the
 two Pallas kernels at the c3 widths (1024 lanes, lane tile 64, Q=64,
-channels 64/128/128) and the plain c3 chunk program at 1024 lanes x chunk
-1024. Nothing runs. The topology is described inside a fixture, never at
+channels 64/128/128) and the plain and fused c3 chunk programs at 1024
+lanes x chunk 1024, each fed the pack's lane-major chunk. Nothing runs. The topology is described inside a fixture, never at
 import: only one process at a time may load libtpu.
 """
 import jax
@@ -86,24 +86,40 @@ def test_fused_step_compiles_for_v5e(one_chip, c3):
     assert "tpu_custom_call" in hlo
 
 
-def test_c3_chunk_program_compiles_for_v5e(one_chip, c3):
+def _compiled_chunk_program(one_chip, c3, use_kernel):
     from repro.core.predictor import init_predictor
     from repro.core.simulator import init_state
     from repro.serving.simnet_engine import SimNetEngine, chunk_specs, lane_param_specs
 
     params = jax.eval_shape(lambda: init_predictor(jax.random.PRNGKey(0), c3)[0])
-    eng = SimNetEngine(params, c3)
+    eng = SimNetEngine(params, c3, use_kernel=use_kernel)
 
     def on_chip(tree):
         return jax.tree_util.tree_map(
             lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree
         )
 
+    xs = chunk_specs(LANES, CHUNK)
+    assert xs["feat"].shape[:2] == (LANES, CHUNK)  # lane-major, as packed
     args = (
         params,
         jax.eval_shape(lambda: init_state(LANES, eng.sim_cfg)),
-        chunk_specs(LANES, CHUNK),
+        xs,
         *lane_param_specs(LANES),
     )
-    compiled = eng._run_chunk.lower(*on_chip(args)).compile()
+    return eng._run_chunk.lower(*on_chip(args)).compile()
+
+
+def test_c3_chunk_program_compiles_for_v5e(one_chip, c3):
+    compiled = _compiled_chunk_program(one_chip, c3, use_kernel=False)
     assert compiled.memory_analysis() is not None
+
+
+def test_fused_c3_chunk_program_compiles_for_v5e(one_chip, c3, monkeypatch):
+    from repro.kernels import ops
+
+    # the kernels pick interpret mode from the default backend, the CPU here
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    compiled = _compiled_chunk_program(one_chip, c3, use_kernel=True)
+    assert compiled.memory_analysis() is not None
+    assert "tpu_custom_call" in compiled.as_text()
