@@ -274,9 +274,10 @@ def apply_trunk(params, x, cfg: PredictorConfig, use_kernel: bool = False):
             qkv = hn @ blk["wqkv"]
             q, k, v = jnp.split(qkv.reshape(B, N, 3, nh, d // nh), 3, axis=2)
             q, k, v = q[:, :, 0], k[:, :, 0], v[:, :, 0]
-            logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(d / nh)
-            probs = jax.nn.softmax(logits, axis=-1)
-            ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(B, N, d)
+            with jax.named_scope("attention"):
+                logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(d / nh)
+                probs = jax.nn.softmax(logits, axis=-1)
+                ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(B, N, d)
             h = h + ctx @ blk["wo"]
             hn = _rms(h, blk["ln2_g"])
             h = h + _dense(blk["ff2"], jax.nn.relu(_dense(blk["ff1"], hn)))

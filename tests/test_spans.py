@@ -4,7 +4,7 @@ scopes.
 A batch's host phases are `telemetry.span`s: each is a `TraceAnnotation`
 on the profiler's timeline and adds its seconds into the batch's
 `BatchReport`. The named scopes (`layout`, `assembly`, `trunk`, `head`,
-`retire`) are HLO metadata only: the compiled chunk program must be the same
+`retire`, and tx6's `attention` inside `trunk`) are HLO metadata only: the compiled chunk program must be the same
 instructions with or without them.
 """
 import contextlib
@@ -29,6 +29,8 @@ PHASES = ("simnet.pack", "simnet.executable", "simnet.stage", "simnet.device_wai
 
 
 def _pcfg(kind):
+    if kind == "tx6":
+        return PredictorConfig(kind=kind, ctx_len=CTX, hidden=16, tx_dim=16, tx_layers=2)
     return PredictorConfig(kind=kind, ctx_len=CTX, hidden=16,
                            channels=(8, 8, 8) if kind == "c3" else (8,))
 
@@ -139,14 +141,17 @@ def _compiled_run_chunk(kind):
     return eng.lower(64, 16).compile().as_text()
 
 
-@pytest.mark.parametrize("kind,scopes", [("c3", {"layout", "assembly", "trunk", "head", "retire"}),
-                                         ("rb7", {"layout", "assembly", "trunk", "head", "retire"}),
+SCOPES = {"layout", "assembly", "trunk", "head", "retire", "attention"}
+
+
+@pytest.mark.parametrize("kind,scopes", [("c3", SCOPES - {"attention"}),
+                                         ("rb7", SCOPES - {"attention"}),
+                                         ("tx6", SCOPES),
                                          ("tf", {"layout", "retire"})])
 def test_named_scopes_leave_run_chunk_the_same(kind, scopes, monkeypatch):
     scoped = _compiled_run_chunk(kind)
     names = set(re.findall(r'op_name="([^"]*)"', scoped))
-    assert {part for n in names for part in n.split("/")} & {
-        "layout", "assembly", "trunk", "head", "retire"} == scopes
+    assert {part for n in names for part in n.split("/")} & SCOPES == scopes
     with monkeypatch.context() as m:
         m.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
         plain = _compiled_run_chunk(kind)
