@@ -606,7 +606,7 @@ def step_throughput(data, quick):
     from repro.serving.simnet_engine import SimNetEngine
 
     lay_traces = traces[: 4 if quick else 8]
-    lay_arrs = [Feat.trace_arrays(t) for t in lay_traces]
+    lay_arrs = [Feat.trace_fields(t) for t in lay_traces]
     lanes_each = 16  # 64+ packed lanes: the serving-shaped batch size
     ctx = 64
     reps = 3  # best-of: sub-second steady passes are scheduler-noisy

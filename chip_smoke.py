@@ -223,13 +223,14 @@ def populated_ring_state(slices, pcfg, n_lanes=256, steps=100):
     )
 
     cfg = SimConfig(ctx_len=pcfg.ctx_len)
-    packed = pack_workloads([F.trace_arrays(t) for t in slices[:n_lanes]], 1, cfg)
+    packed = pack_workloads([F.trace_fields(t) for t in slices[:n_lanes]], 1, cfg)
     lanes = {k: v[0] for k, v in packed.xs.items()}  # the one chunk, (L, T, ...)
     xs = {k: jnp.asarray(v[:, :steps]) for k, v in lanes.items()}
     step = make_sim_scan(None, cfg, emit_outputs=False)
     state, _ = jax.jit(lambda s, x: jax.lax.scan(step, s, chunk_time_major(x)))(
         init_state(n_lanes, cfg), xs)
-    return cfg, state, jnp.asarray(lanes["feat"][:, steps]), jnp.asarray(lanes["addr"][:, steps])
+    nxt = F.expand({k: v[:, steps] for k, v in lanes.items()})
+    return cfg, state, jnp.asarray(nxt["feat"]), jnp.asarray(nxt["addr"])
 
 
 def kernels_vs_oracle(slices, params, pcfg):
@@ -319,7 +320,7 @@ def lane_state_shards(sn, slices, mesh):
     from repro.serving.simnet_engine import chunk_shardings, lane_sharding, state_shardings
 
     eng = sn.engine
-    packed = pack_workloads([F.trace_arrays(t) for t in slices], 1, eng.sim_cfg, chunk=CHUNK)
+    packed = pack_workloads([F.trace_fields(t) for t in slices], 1, eng.sim_cfg, chunk=CHUNK)
     L = packed.n_lanes
     xs_sh, lane_sh = chunk_shardings(mesh), lane_sharding(mesh)
     state = jax.device_put(init_state(L, eng.sim_cfg), state_shardings(mesh))

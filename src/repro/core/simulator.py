@@ -528,13 +528,16 @@ def simulate_trace(trace_arrays: dict, predict_fn, cfg: SimConfig, n_lanes: int)
 class PackedWorkloads:
     """Lanes from many (workload, SimConfig) jobs packed on one lane axis.
 
-    ``xs`` is numpy, lane-major per chunk: feat (n_chunks, L, chunk, 41),
-    addr (n_chunks, L, chunk, 5), is_store (n_chunks, L, chunk), labels
-    (n_chunks, L, chunk, 3), active (n_chunks, L, chunk) bool, so chunk c
-    of a lane is one contiguous block and ``xs[k][c]`` one contiguous put.
-    `chunk_time_major` turns a chunk into the (chunk, L, ...) the scan
-    steps over. Steps past a lane's own sub-trace length are zero-filled
-    and inactive (ragged-length masking).
+    ``xs`` is numpy, lane-major per chunk: one array per integer trace
+    field of `features.FIELDS` (op, register indices and history fields
+    int8, pc and address int32, the three labels float32), each
+    (n_chunks, L, chunk) or (n_chunks, L, chunk, columns), and active
+    (n_chunks, L, chunk) bool, so chunk c of a lane is one contiguous
+    block and ``xs[k][c]`` one contiguous put. `chunk_time_major` expands
+    a chunk into the time-major (chunk, L, ...) feat/addr/is_store/labels
+    the scan steps over. Steps past a lane's own sub-trace length hold each
+    field's pad value, which expands to zeros, and are inactive
+    (ragged-length masking).
     """
 
     xs: dict
@@ -556,11 +559,11 @@ class PackedWorkloads:
 
     @property
     def n_chunks(self) -> int:
-        return int(self.xs["feat"].shape[0])
+        return int(self.xs["active"].shape[0])
 
     @property
     def chunk(self) -> int:
-        return int(self.xs["feat"].shape[2])
+        return int(self.xs["active"].shape[2])
 
     @property
     def n_steps(self) -> int:
@@ -612,19 +615,20 @@ def lane_counts(n_workloads: int, n_lanes: Union[int, Sequence[int]]) -> list:
 
 
 def pack_workloads(
-    trace_arrays_list: Sequence[dict],
+    fields_list: Sequence[dict],
     n_lanes: Union[int, Sequence[int]] = 8,
     cfg: Union[SimConfig, Sequence[SimConfig], None] = None,
     chunk: Optional[int] = None,
     total_lanes: Optional[int] = None,
     buffer: Optional[PackBuffer] = None,
 ) -> PackedWorkloads:
-    """Pack W workloads (each a `trace_arrays` dict) into one lane batch.
+    """Pack W workloads (each a `features.trace_fields` dict) into one lane
+    batch, casting each field to its packed dtype as it is copied.
 
     n_lanes / cfg may be per-workload sequences; the packed scan runs with
     ctx_len = max over jobs, and per-lane retire_width / lane_ctx replay
     each job's own SimConfig exactly. Job w's lane l is rows
-    [l*p, (l+1)*p) of its own arrays, copied chunk by chunk as contiguous
+    [l*p, (l+1)*p) of its own fields, copied chunk by chunk as contiguous
     blocks. ``chunk`` cuts the time axis (None: one chunk as long as the
     longest lane); the last chunk is padded with inactive steps.
     ``total_lanes`` pads the lane axis with dead lanes (executable
@@ -634,7 +638,7 @@ def pack_workloads(
     written into ``buffer`` (a fresh one by default), every element of
     it, so nothing of an earlier pack in a reused buffer survives.
     """
-    W = len(trace_arrays_list)
+    W = len(fields_list)
     if W == 0:
         raise ValueError("pack_workloads needs at least one workload")
     lanes = lane_counts(W, n_lanes)
@@ -658,8 +662,8 @@ def pack_workloads(
             )
 
     per = []
-    for arrs, ln in zip(trace_arrays_list, lanes):
-        T = arrs["feat"].shape[0]
+    for fields, ln in zip(fields_list, lanes):
+        T = F.n_instructions(fields)
         if T < ln:
             raise ValueError(f"workload of {T} instructions cannot fill {ln} lanes")
         per.append(T // ln)
@@ -674,12 +678,15 @@ def pack_workloads(
 
     lead = (n_chunks, L, chunk)
     xs = (buffer or PackBuffer()).views({
-        "feat": (lead + (F.STATIC_END,), np.float32),
-        "addr": (lead + (F.N_ADDR_KEYS,), np.int32),
-        "is_store": (lead, bool),
-        "labels": (lead + (3,), np.float32),
+        **{f.name: (lead + f.shape(1)[1:], f.dtype) for f in F.FIELDS},
         "active": (lead, bool),
     })
+
+    def pad(*at):
+        for f in F.FIELDS:
+            xs[f.name][at] = f.pad
+        xs["active"][at] = False
+
     # dead lanes: id 0 is safe, their totals are exactly zero
     workload_id = np.zeros(L, np.int32)
     retire_width = np.ones(L, np.int32)
@@ -688,30 +695,28 @@ def pack_workloads(
     n_instructions = np.zeros(W, np.int64)
 
     lo = 0
-    for w, (arrs, ln, c, p) in enumerate(zip(trace_arrays_list, lanes, cfgs, per)):
+    for w, (fields, ln, c, p) in enumerate(zip(fields_list, lanes, cfgs, per)):
         hi = lo + ln
         used = p * ln
-        rows = {}
-        for k in ("feat", "addr", "is_store", "labels"):
-            a = np.asarray(arrs[k])
-            rows[k] = a[:used].reshape(ln, p, *a.shape[1:])
+        # each lane's rows of each field: (ln, p, ...) views, no copy; a job
+        # is many small copies, so the loop keeps per-copy work low
+        rows = [(xs[k], a[:used].reshape((ln, p) + a.shape[1:]))
+                for k, a in ((f.name, fields[f.name]) for f in F.FIELDS)]
         for ci in range(n_chunks):
             t0 = ci * chunk
             n = min(chunk, max(p - t0, 0))  # the lanes' live steps in it
-            for k, v in rows.items():
-                xs[k][ci, lo:hi, :n] = v[:, t0 : t0 + n]
+            for dst, v in rows:
+                dst[ci, lo:hi, :n] = v if n == p else v[:, t0 : t0 + n]
             xs["active"][ci, lo:hi, :n] = True
             if n < chunk:  # the ragged tail
-                for v in xs.values():
-                    v[ci, lo:hi, n:] = 0
+                pad(ci, slice(lo, hi), slice(n, None))
         workload_id[lo:hi] = w
         retire_width[lo:hi] = c.retire_width
         lane_ctx[lo:hi] = c.ctx_len
         lane_steps[lo:hi] = p
         n_instructions[w] = used
         lo = hi
-    for v in xs.values():
-        v[:, lo:] = 0
+    pad(slice(None), slice(lo, None))
 
     uniform = all(
         c.retire_width == cfgs[0].retire_width and c.ctx_len == Q for c in cfgs
@@ -724,23 +729,24 @@ def pack_workloads(
 
 
 def chunk_time_major(xs: dict) -> dict:
-    """One chunk of a pack, (L, chunk, ...) per key, to the time-major
-    (chunk, L, ...) that `lax.scan` steps over. Under a lane mesh each
-    device swaps its own lane slice: no communication."""
+    """One chunk of a pack, (L, chunk, ...) per field, expanded by
+    `features.expand` into the time-major (chunk, L, ...) feat, addr,
+    is_store, labels and active that `lax.scan` steps over. Each lane is
+    widened on its own: under a lane mesh each device widens and swaps its
+    own lane slice, with no communication."""
     with jax.named_scope("layout"):
-        return {k: jnp.swapaxes(v, 0, 1) for k, v in xs.items()}
+        t = {k: jnp.swapaxes(v, 0, 1) for k, v in xs.items()}
+        return {**F.expand(t, jnp), "active": t["active"]}
 
 
 def max_packed_steps(
-    trace_arrays_list: Sequence[dict], n_lanes: Union[int, Sequence[int]]
+    fields_list: Sequence[dict], n_lanes: Union[int, Sequence[int]]
 ) -> int:
     """Longest per-lane sub-trace over a prospective pack (= the packed time
     axis before chunk rounding). The session uses this to shrink the
     streaming chunk for small packs so padding stays negligible."""
-    lanes = lane_counts(len(trace_arrays_list), n_lanes)
-    return max(
-        int(a["feat"].shape[0]) // ln for a, ln in zip(trace_arrays_list, lanes)
-    )
+    lanes = lane_counts(len(fields_list), n_lanes)
+    return max(F.n_instructions(f) // ln for f, ln in zip(fields_list, lanes))
 
 
 def workload_totals(state: SimState, packed: PackedWorkloads):
@@ -754,17 +760,19 @@ def workload_totals(state: SimState, packed: PackedWorkloads):
 
 
 def simulate_many(
-    trace_arrays_list: Sequence[dict],
+    fields_list: Sequence[dict],
     predict_fn: Optional[Callable],
     cfg: Union[SimConfig, Sequence[SimConfig], None] = None,
     n_lanes: Union[int, Sequence[int]] = 8,
 ) -> dict:
     """Batched multi-workload simulation: one scan over all packed lanes.
 
-    Teacher-forced (predict_fn=None) per-workload totals are bit-identical
-    to W separate `simulate_trace` calls with each job's own SimConfig.
+    ``fields_list`` holds `features.trace_fields` dicts. Teacher-forced
+    (predict_fn=None) per-workload totals are bit-identical to W separate
+    `simulate_trace` calls on their `features.trace_arrays`, each with the
+    job's own SimConfig.
     """
-    packed = pack_workloads(trace_arrays_list, n_lanes, cfg)
+    packed = pack_workloads(fields_list, n_lanes, cfg)
     rw = None if packed.uniform else jnp.asarray(packed.retire_width)
     lc = None if packed.uniform else jnp.asarray(packed.lane_ctx)
     step = make_sim_scan(

@@ -283,7 +283,7 @@ def _relocate(prog: Program, core_idx: int) -> Program:
     hit/miss structure are unchanged; 0x05000000 is not commensurate with
     the generators' 0x10000000-spaced data bases, so no two cores'
     regions collide, and 8 cores stay inside the int32 address-key budget
-    (`core.features.address_keys`)."""
+    (`core.features.check_fields`)."""
     if core_idx == 0:
         return prog
     prog.addr = np.where(prog.addr > 0, prog.addr + core_idx * 0x05000000, 0)

@@ -12,7 +12,8 @@ Endpoints (all JSON):
 
 - ``POST /v1/jobs``        — submit a job, returns ``{"job_id", "status",
   "correlation_id", "model"}``. The body carries either raw trace arrays
-  (``"trace": {"feat", "addr", "is_store", "labels"}``) or a benchmark
+  (``"trace": {"feat", "addr", "is_store", "labels"}``, the expansion of
+  integer trace fields: other rows are a bad trace) or a benchmark
   spec (``"bench"``/``"n"``/``"o3"`` — the server runs/caches the DES
   trace), plus ``"model"``, ``"lanes"``, ``"id"``, ``"priority"``,
   ``"deadline_ms"``. Errors map to structured bodies: malformed JSON /
@@ -97,9 +98,11 @@ class TransportError(RuntimeError):
 
 
 def _trace_from_wire(spec) -> Dict[str, np.ndarray]:
-    """Rebuild a trace-arrays dict from JSON lists. float32/int32 survive
-    the float64 JSON round-trip exactly, so totals stay bit-identical to
-    an in-process submit of the same arrays."""
+    """Rebuild a trace-arrays dict from JSON lists and invert it into the
+    integer trace fields the engine packs (`features.trace_fields`).
+    float32/int32 survive the float64 JSON round-trip exactly, so totals
+    stay bit-identical to an in-process submit of the same arrays; arrays
+    that are not the expansion of integer fields are a bad trace."""
     from repro.core import features as F
 
     if not isinstance(spec, dict):
@@ -115,18 +118,10 @@ def _trace_from_wire(spec) -> Dict[str, np.ndarray]:
         raise ApiError(400, "bad_trace", f'"trace" is missing key {e}') from None
     except (TypeError, ValueError, OverflowError) as e:
         raise ApiError(400, "bad_trace", f"un-arrayable trace field: {e}") from None
-    T = arrs["feat"].shape[0] if arrs["feat"].ndim == 2 else -1
-    if (arrs["feat"].ndim != 2 or arrs["feat"].shape[1] != F.STATIC_END
-            or arrs["addr"].shape != (T, F.N_ADDR_KEYS)
-            or arrs["is_store"].shape != (T,)
-            or arrs["labels"].shape != (T, 3)):
-        raise ApiError(
-            400, "bad_trace",
-            f"trace shapes must be feat (T, {F.STATIC_END}), addr "
-            f"(T, {F.N_ADDR_KEYS}), is_store (T,), labels (T, 3); got "
-            + str({k: list(v.shape) for k, v in arrs.items()}),
-        )
-    return arrs
+    try:
+        return F.trace_fields(arrs)
+    except ValueError as e:
+        raise ApiError(400, "bad_trace", str(e)) from None
 
 
 class SimServeHTTP:

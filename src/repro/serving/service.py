@@ -128,7 +128,7 @@ class BatchReport:
     # the bytes it holds
     pack_buffer: Dict[str, int] = dataclasses.field(default_factory=dict)
     # host seconds of each phase, timed by its `telemetry.span`: the jobs'
-    # featurizing at submit, then the engine's first pass
+    # trace fields at submit (`F.trace_fields`), then the engine's first pass
     featurize_seconds: float = 0.0
     pack_seconds: float = 0.0
     stage_seconds: float = 0.0  # host-to-device puts and chunk enqueues
@@ -146,7 +146,7 @@ class _Job:
     job_id: int
     model_id: str
     trace: Any  # original TraceLike (kept for the DES-comparison readout)
-    arrs: Dict[str, Any]
+    fields: Dict[str, Any]  # F.trace_fields: what the engine packs
     name: str
     n_lanes: int
     sim_cfg: Optional[SimConfig]
@@ -155,7 +155,7 @@ class _Job:
     priority: int = 0
     deadline_ms: Optional[float] = None
     submit_t: float = 0.0  # service-clock timestamp of admission
-    featurize_seconds: float = 0.0  # host time of F.trace_arrays at submit
+    featurize_seconds: float = 0.0  # host time of F.trace_fields at submit
     corr_id: str = ""  # correlation id stamped on every log record
     result: Optional[WorkloadResult] = None
     batch: Optional[BatchReport] = None
@@ -471,9 +471,12 @@ class SimServe:
         ``priority`` (higher = served sooner; default 0) and
         ``deadline_ms`` (fail the job loudly if still queued this many ms
         after submit; None = no deadline) ride into the scheduler.
-        Raises `QueueFull` when ``max_queue_depth`` pending jobs are
-        already buffered and `ModelUnavailable` when the model's circuit
-        breaker is open — nothing is enqueued in either case."""
+        ``trace`` is a `des.trace.Trace`, a dict of its integer fields, or
+        a trace_arrays dict (`features.trace_fields`). Raises ValueError
+        for fields the pack cannot hold or a dict that is not the
+        expansion of integer fields, `QueueFull` when ``max_queue_depth``
+        pending jobs are already buffered and `ModelUnavailable` when the
+        model's circuit breaker is open — nothing is enqueued in any case."""
         with span("simnet.submit") as ann:
             if model_id is None:
                 model_id = self.registry.ensure_teacher_forced()
@@ -514,13 +517,14 @@ class SimServe:
                         f"{model_id!r} ctx_len {eng_cfg.ctx_len} (the predictor "
                         "input width is fixed)"
                     )
+            # the engine packs the integer trace fields and the device widens
+            # them: here a Trace's fields are only range-checked, and a
+            # trace_arrays dict is inverted into its fields (or refused). A
+            # Trace's arrays are held, not copied, until the batch packs them.
             featurize: Dict[str, float] = {}
-            if isinstance(trace, dict):
-                arrs = trace
-            else:
-                with span("simnet.featurize", featurize, "seconds"):
-                    arrs = F.trace_arrays(trace)
-            T = int(arrs["feat"].shape[0])
+            with span("simnet.featurize", featurize, "seconds"):
+                fields = F.trace_fields(trace)
+            T = F.n_instructions(fields)
             if not 1 <= n_lanes <= T:
                 # statically invalid jobs must be refused here — at drain they
                 # would detonate the shared batch and poison valid batchmates
@@ -560,7 +564,7 @@ class SimServe:
                     job_id=job_id,
                     model_id=model_id,
                     trace=trace,
-                    arrs=arrs,
+                    fields=fields,
                     # the default name derives from the already-unique job_id,
                     # minted under the lock — a shared counter read outside it
                     # minted colliding names under concurrent submits
@@ -770,11 +774,11 @@ class SimServe:
                 self.telemetry.queue_wait_ms.observe(
                     (t_dispatch - j.submit_t) * 1000.0
                 )
-            arrs = [j.arrs for j in jobs]
+            fields = [j.fields for j in jobs]
             lanes = [j.n_lanes for j in jobs]
             cfgs = [j.sim_cfg or engine.sim_cfg for j in jobs]
             cap = min(j.chunk or self.chunk for j in jobs)
-            chunk = chunk_bucket(max_packed_steps(arrs, lanes), cap)
+            chunk = chunk_bucket(max_packed_steps(fields, lanes), cap)
             timeit = jobs[0].timeit
 
             def dispatch():
@@ -782,7 +786,7 @@ class SimServe:
                 # prey), fail an engine that detonates mid-batch
                 faults.fire("batch.execute")
                 return engine.simulate_many(
-                    arrs, n_lanes=lanes, chunk=chunk, cfgs=cfgs, timeit=timeit
+                    fields, n_lanes=lanes, chunk=chunk, cfgs=cfgs, timeit=timeit
                 )
 
             res = self._dispatch_guarded(model_id, jobs, dispatch)

@@ -98,14 +98,14 @@ def state_shardings(mesh):
 
 
 def chunk_specs(n_lanes: int, chunk: int):
-    """ShapeDtypeStructs for one chunk of packed trace input, lane-major
-    as the pack lays it out (`run_chunk` swaps it to time-major)."""
+    """ShapeDtypeStructs for one chunk of packed trace input: each integer
+    trace field and the active mask, lane-major as the pack lays them out
+    (`run_chunk` expands them and swaps to time-major)."""
+    lead = (n_lanes, chunk)
     return {
-        "feat": jax.ShapeDtypeStruct((n_lanes, chunk, F.STATIC_END), jnp.float32),
-        "addr": jax.ShapeDtypeStruct((n_lanes, chunk, F.N_ADDR_KEYS), jnp.int32),
-        "is_store": jax.ShapeDtypeStruct((n_lanes, chunk), jnp.bool_),
-        "labels": jax.ShapeDtypeStruct((n_lanes, chunk, 3), jnp.float32),
-        "active": jax.ShapeDtypeStruct((n_lanes, chunk), jnp.bool_),
+        **{f.name: jax.ShapeDtypeStruct(lead + f.shape(1)[1:], f.dtype)
+           for f in F.FIELDS},
+        "active": jax.ShapeDtypeStruct(lead, jnp.bool_),
     }
 
 
@@ -121,7 +121,7 @@ def chunk_shardings(mesh):
     """A chunk's lane axis (axis 0) over the mesh: each device's slice is
     one contiguous block of the host's pack."""
     s = lane_sharding(mesh)
-    return {"feat": s, "addr": s, "is_store": s, "labels": s, "active": s}
+    return {k: s for k in chunk_specs(1, 1)}
 
 
 class SimNetEngine:
@@ -252,15 +252,15 @@ class SimNetEngine:
 
     def simulate_many(
         self,
-        trace_arrays_list: Sequence[Dict[str, np.ndarray]],
+        fields_list: Sequence[Dict[str, np.ndarray]],
         n_lanes: Union[int, Sequence[int]] = 8,
         chunk: int = 1024,
         cfgs: Union[SimConfig, Sequence[SimConfig], None] = None,
         timeit: bool = False,
     ) -> dict:
-        """Simulate many workloads in one packed lane batch, streaming the
-        time axis through chunked calls to the cache-resident executable
-        with donated state buffers.
+        """Simulate many workloads (each a `features.trace_fields` dict) in
+        one packed lane batch, streaming the time axis through chunked calls
+        to the cache-resident executable with donated state buffers.
 
         The lane axis is bucketed to the next power of two (dead lanes are
         fully masked and contribute nothing), so nearby lane counts reuse
@@ -276,9 +276,9 @@ class SimNetEngine:
         the host pack buffer's reuses and allocations in this call and the
         bytes it holds."""
         with self._pack_lock:
-            return self._simulate_many(trace_arrays_list, n_lanes, chunk, cfgs, timeit)
+            return self._simulate_many(fields_list, n_lanes, chunk, cfgs, timeit)
 
-    def _simulate_many(self, trace_arrays_list, n_lanes, chunk, cfgs, timeit) -> dict:
+    def _simulate_many(self, fields_list, n_lanes, chunk, cfgs, timeit) -> dict:
         t_start = time.perf_counter()
         cache_before = self.cache.counters()
         # host seconds of each phase of the first pass, one span each on
@@ -287,14 +287,14 @@ class SimNetEngine:
             ("pack_seconds", "stage_seconds", "device_wait_seconds", "results_seconds"), 0.0
         )
         with span("simnet.pack", phases, "pack_seconds"):
-            n_live = sum(lane_counts(len(trace_arrays_list), n_lanes))
+            n_live = sum(lane_counts(len(fields_list), n_lanes))
             n_bucket = lane_bucket(n_live)
             if self.mesh is not None:  # every device holds an equal lane slice
                 per = int(np.prod([self.mesh.shape[a] for a in _lane_axes(self.mesh)]))
                 n_bucket = -(-n_bucket // per) * per
             buffer_before = self._pack_buffer.counters()
             packed = pack_workloads(
-                trace_arrays_list, n_lanes, cfgs if cfgs is not None else self.sim_cfg,
+                fields_list, n_lanes, cfgs if cfgs is not None else self.sim_cfg,
                 chunk=chunk, total_lanes=n_bucket, buffer=self._pack_buffer,
             )
             self.pack_buffer_counters = self._pack_buffer.counters()
@@ -390,9 +390,9 @@ class SimNetEngine:
 
     # -- single-workload convenience (same packed scan underneath) -----
 
-    def simulate(self, trace_arrays: Dict[str, np.ndarray], n_lanes: int, chunk: int = 1024,
+    def simulate(self, fields: Dict[str, np.ndarray], n_lanes: int, chunk: int = 1024,
                  timeit: bool = False):
-        res = self.simulate_many([trace_arrays], n_lanes=n_lanes, chunk=chunk, timeit=timeit)
+        res = self.simulate_many([fields], n_lanes=n_lanes, chunk=chunk, timeit=timeit)
         n = int(res["n_instructions"][0])
         return {
             "total_cycles": float(res["workload_cycles"][0]),

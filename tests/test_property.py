@@ -7,6 +7,7 @@ import pytest
 hypothesis = pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
+from conftest import synth_fields  # noqa: E402
 from repro.core import features as F
 from repro.core.simulator import (
     SimConfig,
@@ -108,16 +109,8 @@ def test_retirement_never_reorders(execs, advance):
 
 # ------------------------------------------------------- multi-workload pack
 def _synthetic_arrs(T, seed):
-    rng = np.random.default_rng(seed)
-    is_store = rng.random(T) < 0.2
-    feat = (rng.random((T, F.STATIC_END)) * (rng.random((T, F.STATIC_END)) < 0.3)).astype(np.float32)
-    feat[:, 7] = is_store  # Op.STORE one-hot column must agree with is_store
-    return {
-        "feat": feat,
-        "addr": rng.integers(0, 50, (T, F.N_ADDR_KEYS)).astype(np.int32),
-        "is_store": is_store,
-        "labels": rng.integers(0, 30, (T, 3)).astype(np.float32),
-    }
+    return F.expand(synth_fields(
+        T, seed, store_share=0.2, labels=lambda rng, n: rng.integers(0, 30, (n, 3))))
 
 
 def _check_packed_matches_separate(jobs):
@@ -126,7 +119,7 @@ def _check_packed_matches_separate(jobs):
     cfg = SimConfig(ctx_len=8)
     arrs = [_synthetic_arrs(T, seed) for T, _, seed in jobs]
     lanes = [ln for _, ln, _ in jobs]
-    many = simulate_many(arrs, None, cfg, n_lanes=lanes)
+    many = simulate_many([F.trace_fields(a) for a in arrs], None, cfg, n_lanes=lanes)
     for i, (a, ln) in enumerate(zip(arrs, lanes)):
         ref = simulate_trace(a, None, cfg, ln)
         assert float(many["workload_cycles"][i]) == float(ref["total_cycles"])
@@ -175,7 +168,7 @@ def test_ring_layout_bit_identical_to_roll(jobs):
             SimConfig(ctx_len=ctx, retire_width=rw, layout=layout)
             for _, _, _, ctx, rw in jobs
         ]
-        return simulate_many(arrs, None, cfgs, n_lanes=lanes)
+        return simulate_many([F.trace_fields(a) for a in arrs], None, cfgs, n_lanes=lanes)
 
     roll, ring = run("roll"), run("ring")
     for k in ("lane_cycles", "workload_cycles", "workload_overflow"):
@@ -207,7 +200,7 @@ def test_service_bucketing_never_changes_totals(jobs):
     arrs = [_synthetic_arrs(T, seed) for T, _, seed, _, _ in jobs]
     lanes = [min(ln, T) for T, ln, _, _, _ in jobs]  # a lane needs ≥1 instr
     cfgs = [SimConfig(ctx_len=ctx, retire_width=rw) for _, _, _, ctx, rw in jobs]
-    ref = simulate_many(arrs, None, cfgs, n_lanes=lanes)
+    ref = simulate_many([F.trace_fields(a) for a in arrs], None, cfgs, n_lanes=lanes)
     serve = SimServe()
     serve.register("tf", sim_cfg=SimConfig(ctx_len=8))
     handles = [

@@ -39,6 +39,11 @@ def arrs(traces):
     return [F.trace_arrays(t) for t in traces]
 
 
+@pytest.fixture(scope="module")
+def fields(traces):
+    return [F.trace_fields(t) for t in traces]
+
+
 def _both(cfg_kw):
     return (SimConfig(layout="roll", **cfg_kw), SimConfig(layout="ring", **cfg_kw))
 
@@ -60,7 +65,7 @@ def test_teacher_forced_bit_identical(arrs):
             assert int(roll["overflow"]) == int(ring["overflow"])
 
 
-def test_packed_heterogeneous_bit_identical(arrs):
+def test_packed_heterogeneous_bit_identical(fields):
     """Ragged pack × heterogeneous per-lane retire_width / lane_ctx: the
     ring scan replays every per-workload SimConfig exactly."""
 
@@ -73,8 +78,8 @@ def test_packed_heterogeneous_bit_identical(arrs):
         ]
 
     lanes = [4, 2, 8, 4]
-    roll = simulate_many(arrs, None, cfgs("roll"), n_lanes=lanes)
-    ring = simulate_many(arrs, None, cfgs("ring"), n_lanes=lanes)
+    roll = simulate_many(fields, None, cfgs("roll"), n_lanes=lanes)
+    ring = simulate_many(fields, None, cfgs("ring"), n_lanes=lanes)
     assert_states_identical(
         roll, ring, keys=("lane_cycles", "workload_cycles", "workload_overflow")
     )
@@ -136,12 +141,12 @@ def test_bf16_state_teacher_forced_exact(arrs):
         assert_states_identical(f32, bf16)
 
 
-def test_engine_path_bit_identical(arrs):
+def test_engine_path_bit_identical(fields):
     """Chunked/donated/lane-bucketed engine: ring == roll per workload."""
     from repro.serving.compile_cache import CompileCache
     from repro.serving.simnet_engine import SimNetEngine
 
-    sub = arrs[:2]
+    sub = fields[:2]
 
     def run(layout):
         eng = SimNetEngine(
@@ -154,7 +159,7 @@ def test_engine_path_bit_identical(arrs):
     np.testing.assert_array_equal(roll["workload_overflow"], ring["workload_overflow"])
 
 
-def test_bf16_state_fused_kernel_falls_back(arrs):
+def test_bf16_state_fused_kernel_falls_back(fields):
     """use_kernel + ring + bf16 state must match the UNFUSED bf16 engine
     exactly: the fused kernel assembles in f32 and would skip the bf16
     rounding of the dynamic features, so the engine gates it off."""
@@ -169,7 +174,7 @@ def test_bf16_state_fused_kernel_falls_back(arrs):
         eng = SimNetEngine(
             params, pcfg, scfg, use_kernel=use_kernel, cache=CompileCache()
         )
-        return eng.simulate_many(arrs[:1], n_lanes=4, chunk=256)
+        return eng.simulate_many(fields[:1], n_lanes=4, chunk=256)
 
     np.testing.assert_array_equal(
         run(False)["workload_cycles"], run(True)["workload_cycles"]
@@ -212,7 +217,7 @@ def test_cli_simulate_ring_smoke(capsys, tmp_path):
 
 
 @pytest.mark.slow
-def test_step_layout_wall_clock(arrs):
+def test_step_layout_wall_clock(fields):
     """The reason the ring layout exists: steady-state packed step
     throughput beats the roll layout on ctx_len ≥ 64 packs (the
     acceptance bar is 1.3×; assert a conservative 1.1× so CI noise
@@ -226,7 +231,7 @@ def test_step_layout_wall_clock(arrs):
             sim_cfg=SimConfig(ctx_len=64, layout=layout), cache=CompileCache()
         )
         return min(  # best-of-3: sub-second passes are scheduler-noisy
-            eng.simulate_many(arrs, n_lanes=16, chunk=128, timeit=True)["seconds"]
+            eng.simulate_many(fields, n_lanes=16, chunk=128, timeit=True)["seconds"]
             for _ in range(3)
         )
 

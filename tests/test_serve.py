@@ -9,9 +9,9 @@ The two contract guards for the SimServe redesign:
 """
 import json
 
-import numpy as np
 import pytest
 
+from conftest import synth_arrays
 from repro.core import features as F
 from repro.core.api import SimNet
 from repro.core.simulator import SimConfig, simulate_many as core_simulate_many
@@ -321,30 +321,13 @@ def test_zoo_sweep_compiles_each_executable_once(traces):
 
 # ------------------------------------------------- bucketing exactness
 
-def _synth(T, seed):
-    rng = np.random.default_rng(seed)
-    is_store = rng.random(T) < 0.3
-    feat = rng.random((T, F.STATIC_END)).astype(np.float32)
-    feat[:, 7] = is_store  # Op.STORE one-hot column must agree with is_store
-    return {
-        "feat": feat,
-        "addr": rng.integers(0, 50, (T, F.N_ADDR_KEYS)).astype(np.int32),
-        "is_store": is_store,
-        "labels": np.stack([
-            rng.integers(0, 4, T),
-            rng.integers(1, 12, T),
-            rng.integers(1, 6, T),
-        ], axis=1).astype(np.float32),
-    }
-
-
 def test_dead_lane_masking_exact_vs_unbucketed():
     """5 live lanes bucket to 8; the three dead lanes must contribute
     exactly nothing (bit-identical totals vs the unbucketed core scan)."""
-    jobs = [_synth(96, 0), _synth(80, 1)]
+    jobs = [synth_arrays(96, 0), synth_arrays(80, 1)]
     lanes = [3, 2]
     cfg = SimConfig(ctx_len=8)
-    ref = core_simulate_many(jobs, None, cfg, n_lanes=lanes)
+    ref = core_simulate_many([F.trace_fields(j) for j in jobs], None, cfg, n_lanes=lanes)
     res = SimNet(sim_cfg=cfg).simulate_many(jobs, n_lanes=lanes)
     for i, w in enumerate(res):
         assert w.total_cycles == float(ref["workload_cycles"][i])

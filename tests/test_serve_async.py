@@ -13,10 +13,9 @@ machinery under test is identical for predictor models.
 import threading
 import time
 
-import numpy as np
 import pytest
 
-from repro.core import features as F
+from conftest import synth_arrays
 from repro.core.session import SimNet
 from repro.core.simulator import SimConfig
 from repro.serving.compile_cache import CompileCache
@@ -26,24 +25,7 @@ from repro.serving.service import QueueFull, SimServe
 CFG = SimConfig(ctx_len=8)
 
 
-def _synth(T, seed):
-    rng = np.random.default_rng(seed)
-    is_store = rng.random(T) < 0.3
-    feat = rng.random((T, F.STATIC_END)).astype(np.float32)
-    feat[:, 7] = is_store  # Op.STORE one-hot column must agree with is_store
-    return {
-        "feat": feat,
-        "addr": rng.integers(0, 50, (T, F.N_ADDR_KEYS)).astype(np.int32),
-        "is_store": is_store,
-        "labels": np.stack([
-            rng.integers(0, 4, T),
-            rng.integers(1, 12, T),
-            rng.integers(1, 6, T),
-        ], axis=1).astype(np.float32),
-    }
-
-
-TRACES = {f"w{i}": _synth(64 + 16 * i, i) for i in range(4)}
+TRACES = {f"w{i}": synth_arrays(64 + 16 * i, i) for i in range(4)}
 MODELS = ("alpha", "beta")  # ≥2 resident models (label-replay engines)
 
 

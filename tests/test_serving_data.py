@@ -33,7 +33,7 @@ def test_simnet_engine_matches_direct_scan(small_trace):
     scfg = SimConfig(ctx_len=16)
     arrs = F.trace_arrays(small_trace)
     engine = SimNetEngine(params, pcfg, scfg)
-    res_e = engine.simulate(arrs, n_lanes=4, chunk=256)
+    res_e = engine.simulate(F.trace_fields(small_trace), n_lanes=4, chunk=256)
     predict = make_predict_fn(params, pcfg)
     res_d = simulate_trace(arrs, predict, scfg, n_lanes=4)
     # chunked-scan engine must agree with the single-scan reference wherever

@@ -33,14 +33,19 @@ def arrs(traces):
     return [F.trace_arrays(t) for t in traces]
 
 
-def test_session_totals_bit_identical_to_core(traces, arrs):
+@pytest.fixture(scope="module")
+def fields(traces):
+    return [F.trace_fields(t) for t in traces]
+
+
+def test_session_totals_bit_identical_to_core(traces, fields):
     """Teacher-forced pack through the session (engine path) vs the
     one-shot core scan: totals must be bit-identical, not just close."""
     cfg = SimConfig(ctx_len=32)
     lanes = [4, 2, 8, 4]
     sn = SimNet(sim_cfg=cfg)
     res = sn.simulate_many(traces, n_lanes=lanes)
-    ref = core_simulate_many(arrs, None, cfg, n_lanes=lanes)
+    ref = core_simulate_many(fields, None, cfg, n_lanes=lanes)
     for i, w in enumerate(res):
         assert w.total_cycles == float(ref["workload_cycles"][i])
         assert w.n_instructions == int(ref["n_instructions"][i])
@@ -48,7 +53,7 @@ def test_session_totals_bit_identical_to_core(traces, arrs):
     assert res.total_cycles == float(ref["total_cycles"])
 
 
-def test_session_heterogeneous_cfgs_bit_identical(traces, arrs):
+def test_session_heterogeneous_cfgs_bit_identical(traces, fields):
     """Per-workload SimConfigs through the session replay each job's own
     config exactly inside the shared engine scan."""
     cfgs = [
@@ -59,7 +64,7 @@ def test_session_heterogeneous_cfgs_bit_identical(traces, arrs):
     ]
     sn = SimNet(sim_cfg=SimConfig(ctx_len=32))
     res = sn.simulate_many(traces, n_lanes=4, sim_cfgs=cfgs)
-    ref = core_simulate_many(arrs, None, cfgs, n_lanes=4)
+    ref = core_simulate_many(fields, None, cfgs, n_lanes=4)
     for i, w in enumerate(res):
         assert w.total_cycles == float(ref["workload_cycles"][i])
         assert w.overflow == int(ref["workload_overflow"][i])

@@ -34,12 +34,17 @@ def arrs(traces):
     return [F.trace_arrays(t) for t in traces]
 
 
-def test_packed_matches_separate_exact(arrs):
+@pytest.fixture(scope="module")
+def fields(traces):
+    return [F.trace_fields(t) for t in traces]
+
+
+def test_packed_matches_separate_exact(arrs, fields):
     """Round-trip: pack → simulate → per-workload totals bit-identical to
     N separate simulate_trace calls (teacher forcing)."""
     cfg = SimConfig(ctx_len=32)
     lanes = [4, 2, 8, 4]
-    many = simulate_many(arrs, None, cfg, n_lanes=lanes)
+    many = simulate_many(fields, None, cfg, n_lanes=lanes)
     for i, (a, ln) in enumerate(zip(arrs, lanes)):
         ref = simulate_trace(a, None, cfg, ln)
         assert float(many["workload_cycles"][i]) == float(ref["total_cycles"])
@@ -51,6 +56,13 @@ def _lane_rows(a):
     """A pack key, (n_chunks, L, chunk, ...), as each lane's whole time
     axis: (L, n_chunks * chunk, ...)."""
     return np.concatenate(list(a), axis=1)
+
+
+def _expanded_lane_rows(packed):
+    """The pack's fields expanded on the host, each lane's whole time axis:
+    {feat, addr, is_store, labels, active}: (L, n_chunks * chunk, ...)."""
+    rows = {k: _lane_rows(v) for k, v in packed.xs.items()}
+    return {**F.expand(rows), "active": rows["active"]}
 
 
 def _old_time_major_pack(arrs, lanes, cfgs, pad_to, total_lanes):
@@ -86,37 +98,41 @@ def _old_time_major_pack(arrs, lanes, cfgs, pad_to, total_lanes):
 
 
 @pytest.mark.parametrize("chunk,total_lanes", [(256, None), (256, 32), (100, 19), (None, None)])
-def test_lane_major_pack_matches_time_major(arrs, chunk, total_lanes):
+def test_lane_major_pack_matches_time_major(arrs, fields, chunk, total_lanes):
     """Ragged jobs, mixed SimConfigs and 18 live lanes (not a power of two):
-    the lane-major pack swapped back to time-major equals the old
-    time-major pack element for element, dead lanes included."""
+    the lane-major pack of integer fields, expanded and swapped back to
+    time-major, equals the old time-major pack of features element for
+    element, dead lanes included."""
     lanes = [4, 2, 8, 4]
     cfgs = [SimConfig(ctx_len=16, retire_width=2), SimConfig(ctx_len=32),
             SimConfig(ctx_len=8, retire_width=4), SimConfig(ctx_len=32, retire_width=1)]
-    packed = pack_workloads(arrs, lanes, cfgs, chunk=chunk, total_lanes=total_lanes)
+    packed = pack_workloads(fields, lanes, cfgs, chunk=chunk, total_lanes=total_lanes)
     per = [a["feat"].shape[0] // ln for a, ln in zip(arrs, lanes)]
     L = total_lanes or sum(lanes)
     want, lane = _old_time_major_pack(arrs, lanes, cfgs, chunk or max(per), L)
     assert packed.n_lanes == L
-    for k, v in want.items():
-        got = packed.xs[k]
+    for k, got in packed.xs.items():
         assert got.shape[:3] == (packed.n_chunks, L, packed.chunk)
-        time_major = np.swapaxes(got, 1, 2).reshape((packed.n_steps, L) + got.shape[3:])
+    expanded = _expanded_lane_rows(packed)
+    for k, v in want.items():
+        time_major = np.swapaxes(expanded[k], 0, 1)
         assert time_major.dtype == v.dtype
         np.testing.assert_array_equal(time_major, v, err_msg=k)
     for k, v in lane.items():
         np.testing.assert_array_equal(getattr(packed, k), v, err_msg=k)
 
 
-def test_ragged_lengths_masked(arrs):
+def test_ragged_lengths_masked(fields):
     """Lanes from shorter workloads freeze once their sub-trace ends; the
     packed time axis is max(per-lane length) rounded up to the chunk."""
-    packed = pack_workloads(arrs, n_lanes=4, cfg=SimConfig(ctx_len=16), chunk=256)
-    per = [a["feat"].shape[0] // 4 for a in arrs]
+    packed = pack_workloads(fields, n_lanes=4, cfg=SimConfig(ctx_len=16), chunk=256)
+    per = [F.n_instructions(f) // 4 for f in fields]
     assert packed.n_steps == ((max(per) + 255) // 256) * 256
     assert packed.chunk == 256
-    active = _lane_rows(packed.xs["active"])  # (L, T)
-    labels = _lane_rows(packed.xs["labels"])  # (L, T, 3)
+    expanded = _expanded_lane_rows(packed)
+    active = expanded["active"]  # (L, T)
+    labels = expanded["labels"]  # (L, T, 3)
+    assert not expanded["feat"][~active].any()
     lo = 0
     for w, p in enumerate(per):
         assert active[lo : lo + 4, :p].all()
@@ -129,7 +145,7 @@ def test_ragged_lengths_masked(arrs):
     assert labels[:, max(per):].sum() == 0.0
 
 
-def test_engine_reused_pack_buffer_matches_fresh_engine(arrs):
+def test_engine_reused_pack_buffer_matches_fresh_engine(fields):
     """One engine packs a large ragged batch, then a smaller full one, then
     a third shape, into the host buffer it keeps: every call's totals are
     bit-identical to a fresh engine's, so no stale row, dead lane or
@@ -149,10 +165,10 @@ def test_engine_reused_pack_buffer_matches_fresh_engine(arrs):
         return SimNetEngine(params, pcfg, SimConfig(ctx_len=16), cache=cache)
 
     packs = [
-        (arrs, [4, 2, 8, 4], 256),  # ragged, 18 lanes in a 32-lane bucket
-        ([{k: v[:1024] for k, v in a.items()} for a in arrs[:2]],
+        (fields, [4, 2, 8, 4], 256),  # ragged, 18 lanes in a 32-lane bucket
+        ([{k: v[:1024] for k, v in f.items()} for f in fields[:2]],
          [4, 4], 128),  # two full chunks, 8 lanes, no dead lane
-        (arrs[1:3], [2, 1], 512),  # a third shape: 3 lanes in a bucket of 4
+        (fields[1:3], [2, 1], 512),  # a third shape: 3 lanes in a bucket of 4
     ]
     eng = engine()
     for trs, lanes, chunk in packs:
@@ -170,7 +186,7 @@ def test_engine_reused_pack_buffer_matches_fresh_engine(arrs):
     assert again["pack_buffer"]["bytes"] > 0
 
 
-def test_heterogeneous_configs_exact(arrs):
+def test_heterogeneous_configs_exact(arrs, fields):
     """Workloads × SimConfigs: per-lane retire width and context capacity
     replay each job's own config exactly inside the shared scan."""
     cfgs = [
@@ -180,19 +196,19 @@ def test_heterogeneous_configs_exact(arrs):
         SimConfig(ctx_len=32, retire_width=1),
     ]
     lanes = [4, 2, 8, 4]
-    many = simulate_many(arrs, None, cfgs, n_lanes=lanes)
+    many = simulate_many(fields, None, cfgs, n_lanes=lanes)
     for i, (a, c, ln) in enumerate(zip(arrs, cfgs, lanes)):
         ref = simulate_trace(a, None, c, ln)
         assert float(many["workload_cycles"][i]) == float(ref["total_cycles"])
         assert int(many["workload_overflow"][i]) == int(ref["overflow"])
 
 
-def test_rejects_mismatched_shared_config_fields(arrs):
+def test_rejects_mismatched_shared_config_fields(fields):
     """Only ctx_len/retire_width are replayed per lane; packing configs that
     differ elsewhere (e.g. max_latency) must fail loudly, not silently
     clip with the wrong bound."""
     with pytest.raises(ValueError, match="other SimConfig fields"):
-        pack_workloads(arrs[:2], 2, cfg=[SimConfig(max_latency=50.0), SimConfig()])
+        pack_workloads(fields[:2], 2, cfg=[SimConfig(max_latency=50.0), SimConfig()])
 
 
 def test_overflow_accounted_per_workload():
@@ -200,25 +216,20 @@ def test_overflow_accounted_per_workload():
     entries — a saturating workload must not leak into a well-behaved one."""
     T = 64
 
-    def synth(exec_lat):
-        return {
-            "feat": np.zeros((T, F.STATIC_END), np.float32),
-            "addr": np.zeros((T, F.N_ADDR_KEYS), np.int32),
-            "is_store": np.zeros(T, bool),
-            "labels": np.stack(
-                [np.zeros(T), np.full(T, exec_lat), np.zeros(T)], axis=1
-            ).astype(np.float32),
-        }
+    def synth(fetch_lat, exec_lat):
+        fields = {f.name: np.zeros(f.shape(T), f.dtype) for f in F.FIELDS}
+        fields["fetch_lat"][:] = fetch_lat
+        fields["exec_lat"][:] = exec_lat
+        return fields
 
     cfg = SimConfig(ctx_len=4)
     # workload 0: fetch 0 + huge exec → everything stays in flight → overflow
     # workload 1: exec 1 with fetch 0... also saturates, so give it fetch 1
-    busy = synth(1e4)
-    calm = synth(1.0)
-    calm["labels"][:, 0] = 1.0
+    busy = synth(0.0, 1e4)
+    calm = synth(1.0, 1.0)
     many = simulate_many([busy, calm], None, cfg, n_lanes=2)
-    ref_busy = simulate_trace(busy, None, cfg, 2)
-    ref_calm = simulate_trace(calm, None, cfg, 2)
+    ref_busy = simulate_trace(F.expand(busy), None, cfg, 2)
+    ref_calm = simulate_trace(F.expand(calm), None, cfg, 2)
     assert int(many["workload_overflow"][0]) == int(ref_busy["overflow"]) > 0
     assert int(many["workload_overflow"][1]) == int(ref_calm["overflow"]) == 0
     assert float(many["workload_cycles"][1]) == float(ref_calm["total_cycles"])
@@ -292,11 +303,11 @@ def test_engine_simulate_many_matches_core(traces):
 
     pcfg = PredictorConfig(kind="c1", ctx_len=16)
     params, _ = init_predictor(jax.random.PRNGKey(0), pcfg)
-    arrs2 = [F.trace_arrays(t) for t in traces[:2]]
+    fields2 = [F.trace_fields(t) for t in traces[:2]]
     engine = SimNetEngine(params, pcfg, SimConfig(ctx_len=16))
-    res_e = engine.simulate_many(arrs2, n_lanes=4, chunk=128)
+    res_e = engine.simulate_many(fields2, n_lanes=4, chunk=128)
     predict = make_predict_fn(params, pcfg)
-    res_c = simulate_many(arrs2, predict, SimConfig(ctx_len=16), n_lanes=4)
+    res_c = simulate_many(fields2, predict, SimConfig(ctx_len=16), n_lanes=4)
     np.testing.assert_allclose(
         res_e["workload_cycles"], np.asarray(res_c["workload_cycles"]), rtol=1e-6
     )
@@ -340,7 +351,7 @@ def test_lane_sharded_session_matches_des_and_one_device():
     assert out["sharded"] == out["des"] == out["single"]
 
 
-def test_engine_pack_buffer_shared_by_threads(arrs):
+def test_engine_pack_buffer_shared_by_threads(fields):
     """Threads calling one engine's simulate_many at once share its host
     pack buffer: each call still returns what it returns alone."""
     import sys
@@ -350,7 +361,7 @@ def test_engine_pack_buffer_shared_by_threads(arrs):
     from repro.serving.simnet_engine import SimNetEngine
 
     eng = SimNetEngine(None, None, SimConfig(ctx_len=16), cache=CompileCache())
-    packs = [(arrs, [4, 2, 8, 4]), (arrs[::-1], [2, 8, 4, 4]), (arrs[1:3], [2, 1])]
+    packs = [(fields, [4, 2, 8, 4]), (fields[::-1], [2, 8, 4, 4]), (fields[1:3], [2, 1])]
     want = [eng.simulate_many(a, n_lanes=ln, chunk=256)["workload_cycles"] for a, ln in packs]
     got, errors = {}, []
 

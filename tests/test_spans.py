@@ -106,11 +106,14 @@ def test_batch_report_phase_counters(kind, timeit, traces):
 
 
 def test_arrays_skip_featurize(traces):
+    """trace_arrays dicts are no longer widened at submit, but they are
+    inverted into integer fields there: that is timed by the same span
+    and counter as a Trace's checks."""
     from repro.core import features as F
 
     sn = _session("tf")
     sn.simulate_many([F.trace_arrays(t) for t in traces], n_lanes=2)
-    assert sn.service.batches[-1].featurize_seconds == 0.0
+    assert sn.service.batches[-1].featurize_seconds > 0.0
 
 
 def test_span_adds_into_its_counter_even_when_the_body_raises():

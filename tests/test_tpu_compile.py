@@ -105,7 +105,8 @@ def _compiled_chunk_program(one_chip, pcfg, use_kernel, lanes=LANES, chunk=CHUNK
         )
 
     xs = chunk_specs(lanes, chunk)
-    assert xs["feat"].shape[:2] == (lanes, chunk)  # lane-major, as packed
+    # lane-major integer trace fields, as packed
+    assert all(v.shape[:2] == (lanes, chunk) for v in xs.values())
     args = (
         params,
         jax.eval_shape(lambda: init_state(lanes, eng.sim_cfg)),
