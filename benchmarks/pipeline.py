@@ -15,11 +15,9 @@ Artifacts (artifacts/simnet/):
   table4.json              model zoo: prediction err, sim err, MFlops (Table 4)
   fig56_cpi.json           per-benchmark CPIs + phase curves (Figs. 5, 6)
   fig7_subtrace.json       parallel-lane error vs sub-trace size (Fig. 7)
-  fig89_throughput.json    throughput vs lanes + DES baseline (Figs. 8, 9)
-  packed_throughput.json   batched engine: packed vs sequential + SimServe
-                           zoo sweep (compile-cache hits/misses/seconds) +
-                           multicore contention section (solo-trained vs
-                           contention-augmented on held-out co-run traces)
+  contention_chaos.json    multicore contention section (solo-trained vs
+                           contention-augmented on held-out co-run traces) +
+                           seeded chaos drill section
   table5_usecases.json     design-space relative accuracy (Table 5 / §5)
   a64fx.json               second-processor-config accuracy (§4.1)
 """
@@ -218,24 +216,6 @@ def step_fig7(data, quick):
     _save_json("fig7_subtrace.json", out)
 
 
-def step_fig89(data, quick):
-    if _exists("fig89_throughput.json"):
-        return
-    sn = load_session("c3_hybrid")
-    tr = data["sim_traces"][0]
-    out = {"points": [], "des_ips": None, "hardware": "1-core CPU container (TPU is target; see roofline)"}
-    # DES baseline throughput
-    prog = get_benchmark("sim_loop", 20000)
-    t0 = time.time()
-    O3Simulator(O3Config()).run(prog)
-    out["des_ips"] = 20000 / (time.time() - t0)
-    for lanes in ([4, 16, 64, 256] if not quick else [4, 16]):
-        res = sn.simulate(tr, n_lanes=lanes, timeit=True)  # steady-state IPS
-        out["points"].append({"lanes": lanes, "ips": float(res.throughput_ips)})
-        print(f"[pipeline] fig89 lanes={lanes}: {res.throughput_ips:.0f} IPS", flush=True)
-    _save_json("fig89_throughput.json", out)
-
-
 def step_table5(data, quick):
     if _exists("table5_usecases.json"):
         return
@@ -283,383 +263,6 @@ def step_table5(data, quick):
     _save_json("table5_usecases.json", out)
 
 
-def step_throughput(data, quick):
-    """Packed vs sequential execution of the same workload set (the batched
-    multi-workload engine's headline number: instructions/sec both ways),
-    plus the SimServe readout: a zoo sweep where every same-architecture
-    model reuses ONE resident executable (cache hits ≥ misses) instead of
-    paying per-model first_call compiles."""
-    prior = {}
-    if _exists("packed_throughput.json"):
-        prior = json.loads((ART / "packed_throughput.json").read_text())
-        if "packed" in prior:
-            return
-        # file holds only other steps' sections (e.g. contention) — keep them
-    from repro.core.api import SimServe
-    from repro.serving.compile_cache import CompileCache
-
-    art = load_session("c3_hybrid").artifact
-    traces = (data["ml_eval"] + data["sim_traces"])[: 6 if quick else 12]
-    lanes = 8
-    # sequential: a fresh engine per workload, each on its own COLD cache —
-    # one compile+dispatch cycle per workload, the pre-SimServe pipeline
-    # behaviour (per-session jit wrappers, exact-length chunks that never
-    # matched — the serialization the batched engine's motivation calls out)
-    seq_caches = [CompileCache() for _ in traces]
-    t0 = time.time()
-    seq = [SimNet(art, cache=c).simulate(tr, n_lanes=lanes, timeit=True)
-           for tr, c in zip(traces, seq_caches)]
-    seq_run = sum(r.seconds for r in seq)  # compiled-call time only
-    # timeit executes each compiled pass twice (warmup + timed); subtract
-    # the timed re-runs so the baseline is an honest single pass
-    # (compile + one execution per workload), same shape as the packed side
-    seq_wall = (time.time() - t0) - seq_run
-    n_seq = sum(r.total_instructions for r in seq)
-    packed_cache = CompileCache()
-    many = SimNet(art, cache=packed_cache).simulate_many(
-        traces, n_lanes=lanes, timeit=True
-    )
-    out = {
-        "n_workloads": len(traces),
-        "lanes_per_workload": lanes,
-        "sequential": {"ips": n_seq / seq_run, "seconds": seq_run,
-                       "wall_seconds": seq_wall,  # per-call compiles + 1 run each
-                       "n_instructions": n_seq,
-                       "cache": {k: sum(c.stats()[k] for c in seq_caches) for k in
-                                 ("hits", "misses", "compile_seconds")}},
-        "packed": {"ips": many.throughput_ips, "seconds": many.seconds,
-                   "wall_seconds": many.first_call_seconds,  # one compile+run
-                   "n_instructions": many.total_instructions,
-                   "cache": dict(many.cache)},
-        # headline: whole-sweep wall clock, packed vs one-call-per-workload
-        "speedup_wall": seq_wall / many.first_call_seconds,
-        # steady state: compiled call vs compiled call
-        "speedup_steady": many.throughput_ips / (n_seq / seq_run),
-    }
-    print(f"[pipeline] throughput: sequential {out['sequential']['ips']:.0f} IPS, "
-          f"packed {out['packed']['ips']:.0f} IPS "
-          f"({out['speedup_wall']:.2f}x wall, {out['speedup_steady']:.2f}x steady)",
-          flush=True)
-
-    # --- SimServe zoo sweep: executable reuse instead of per-model -------
-    # first_call compiles. Wave 1 makes each distinct architecture's
-    # executable resident (one compile each — same-shape models share);
-    # wave 2 is the steady-traffic readout: every batch is a cache hit.
-    zoo_ids = [model_id(k, o) for k, o, _ in ZOO
-               if k not in SLOW_KINDS and k != "ithemal_lstm2"]
-    serve_cache = CompileCache()
-    serve = SimServe(cache=serve_cache)
-    resident = []
-    for mid in zoo_ids:
-        path = ART / "models" / mid
-        if PredictorArtifact.exists(path):
-            serve.register(mid, str(path))
-            resident.append(mid)
-    serve_traces = traces[: 3 if quick else 6]
-    waves = []
-    t0 = time.time()
-    for wave in range(2):
-        tw = time.time()
-        for mid in resident:
-            for tr in serve_traces:
-                serve.submit(tr, mid, n_lanes=lanes)
-        n_before = len(serve.batches)
-        serve.drain()
-        waves.append({
-            "wall_seconds": time.time() - tw,
-            "per_model_first_call_seconds": {
-                b.model_id: b.first_call_seconds
-                for b in serve.batches[n_before:]
-            },
-        })
-    serve_wall = time.time() - t0
-    st = serve.stats()
-    out["serve_zoo"] = {
-        "models": resident,
-        "n_workloads": len(serve_traces),
-        "n_jobs": st["jobs_completed"],
-        "wall_seconds": serve_wall,
-        "batches": st["batches"],
-        "jobs_per_batch": st["jobs_per_batch"],
-        "waves": waves,
-        "cache": {k: st["cache"][k] for k in ("hits", "misses", "compile_seconds")},
-        "executables": st["cache"]["executables"],
-    }
-    print(f"[pipeline] serve_zoo: {st['jobs_completed']} jobs over {len(resident)} "
-          f"resident models in {serve_wall:.1f}s — cache {out['serve_zoo']['cache']}",
-          flush=True)
-
-    # --- serve_async: background drain loop vs sequential drain ----------
-    # N threaded clients submit a (model × workload) grid against the
-    # running drain loop (max_wait_ms batch window, round-robin across
-    # models) vs the same grid dispatched one-batch-per-job sequentially:
-    # totals must match bit-for-bit, jobs/batch is the packing win. Both
-    # sides ride the warm serve_cache so this measures scheduling, not
-    # compiles.
-    import threading
-
-    async_models = resident[:2] if len(resident) >= 2 else resident
-    if async_models:
-        grid = [(mid, tr) for mid in async_models for tr in serve_traces]
-        n_clients = 4
-
-        seq_serve = SimServe(cache=serve_cache)
-        for mid in async_models:
-            seq_serve.register(mid, str(ART / "models" / mid))
-        t0 = time.time()
-        seq_totals = {}
-        for mid, tr in grid:
-            h = seq_serve.submit(tr, mid, n_lanes=lanes)
-            seq_serve.drain()  # one batch per job: the no-async baseline
-            seq_totals[(mid, tr.name)] = h.result().total_cycles
-        seq_wall = time.time() - t0
-
-        async_serve = SimServe(cache=serve_cache, max_wait_ms=10.0)
-        for mid in async_models:
-            async_serve.register(mid, str(ART / "models" / mid))
-        async_totals = {}
-
-        def client(c):
-            hs = [(mid, tr.name, async_serve.submit(tr, mid, n_lanes=lanes))
-                  for mid, tr in grid[c::n_clients]]
-            for mid, name, h in hs:
-                async_totals[(mid, name)] = h.result(timeout=600).total_cycles
-
-        t0 = time.time()
-        with async_serve:
-            clients = [threading.Thread(target=client, args=(c,))
-                       for c in range(n_clients)]
-            for t in clients:
-                t.start()
-            for t in clients:
-                t.join()
-        async_wall = time.time() - t0
-        ast = async_serve.stats()
-        out["serve_async"] = {
-            "models": async_models,
-            "n_clients": n_clients,
-            "n_jobs": len(grid),
-            "totals_match": async_totals == seq_totals,
-            "sequential": {"wall_seconds": seq_wall,
-                           "jobs_per_batch": seq_serve.stats()["jobs_per_batch"],
-                           "batches": seq_serve.stats()["batches"]},
-            "async": {"wall_seconds": async_wall,
-                      "jobs_per_batch": ast["jobs_per_batch"],
-                      "batches": ast["batches"],
-                      "loop_errors": ast["loop_errors"]},
-        }
-        sa = out["serve_async"]
-        print(f"[pipeline] serve_async: {len(grid)} jobs × {n_clients} clients — "
-              f"async {sa['async']['jobs_per_batch']:.1f} jobs/batch in "
-              f"{async_wall:.1f}s vs sequential 1.0 in {seq_wall:.1f}s, "
-              f"totals_match={sa['totals_match']}", flush=True)
-
-        # --- serve_http: the same grid over the wire ---------------------
-        # N real HTTP client threads POST raw trace arrays to a live
-        # ephemeral-port front-end and poll /v1/jobs/<id> — totals must
-        # stay bit-identical to the in-process sequential baseline
-        # (float32/int32 arrays survive the JSON float64 round trip
-        # exactly) with batches still shared across clients. On the warm
-        # serve_cache this measures wire + scheduling overhead only.
-        from repro.core import features as FeatHTTP
-        from repro.serving.http import SimServeHTTP, http_request, wait_job
-
-        wire = {
-            tr.name: {k: np.asarray(v).tolist()
-                      for k, v in FeatHTTP.trace_arrays(tr).items()}
-            for _, tr in grid[:len(serve_traces)]
-        }
-        http_serve = SimServe(cache=serve_cache, max_wait_ms=10.0)
-        for mid in async_models:
-            http_serve.register(mid, str(ART / "models" / mid))
-        http_totals = {}
-
-        def http_client(c, base):
-            posted = [
-                (mid, tr.name,
-                 http_request(f"{base}/v1/jobs", "POST",
-                              {"trace": wire[tr.name], "model": mid,
-                               "lanes": lanes, "id": tr.name}))
-                for mid, tr in grid[c::n_clients]
-            ]
-            for mid, name, (st_, body) in posted:
-                assert st_ == 202, (st_, body)
-                done = wait_job(base, body["job_id"], timeout=600)
-                assert done["status"] == "done", done
-                http_totals[(mid, name)] = done["result"]["total_cycles"]
-
-        t0 = time.time()
-        with SimServeHTTP(http_serve) as front:
-            clients = [threading.Thread(target=http_client,
-                                        args=(c, front.url))
-                       for c in range(n_clients)]
-            for t in clients:
-                t.start()
-            for t in clients:
-                t.join()
-            _, hst = http_request(f"{front.url}/v1/stats")
-        http_serve.stop()
-        http_wall = time.time() - t0
-        out["serve_http"] = {
-            "models": async_models,
-            "n_clients": n_clients,
-            "n_jobs": len(grid),
-            "totals_match": http_totals == seq_totals,
-            "wall_seconds": http_wall,
-            "jobs_per_batch": hst["jobs_per_batch"],
-            "batches": hst["batches"],
-            "loop_errors": hst["loop_errors"],
-            "service_ms_p99": hst["telemetry"]["service_ms"]["p99"],
-            "queue_wait_ms_p99": hst["telemetry"]["queue_wait_ms"]["p99"],
-        }
-        sh = out["serve_http"]
-        print(f"[pipeline] serve_http: {len(grid)} jobs × {n_clients} HTTP "
-              f"clients — {sh['jobs_per_batch']:.1f} jobs/batch in "
-              f"{http_wall:.1f}s, p99 service {sh['service_ms_p99']:.0f} ms, "
-              f"totals_match={sh['totals_match']}", flush=True)
-
-        # --- serve_fleet: the same grid through replica SUBPROCESSES -----
-        # 1-replica vs 2-replica lanes behind the router (each replica is a
-        # real `repro serve --http 0` process with its own interpreter and
-        # cold compile cache — wall clock includes fleet startup, the price
-        # of process isolation), then a failover lane: one replica is
-        # SIGKILLed mid-run and every accepted job must still complete on
-        # the survivor via the router's resubmit policy. Totals must stay
-        # bit-identical to the in-process sequential baseline in all lanes.
-        from repro.serving.fleet import Fleet
-        from repro.serving.router import route_jobs
-
-        models_spec = {mid: str(ART / "models" / mid) for mid in async_models}
-        payloads = [{"id": f"fleet-{c}", "trace": wire[tr.name], "model": mid,
-                     "lanes": lanes} for c, (mid, tr) in enumerate(grid)]
-
-        def fleet_totals(entries):
-            return {(mid, tr.name): e["result"]["total_cycles"]
-                    for (mid, tr), e in zip(grid, entries)
-                    if e["status"] == "done"}
-
-        out["serve_fleet"] = {"models": async_models, "n_jobs": len(grid)}
-        for n_rep in (1, 2):
-            t0 = time.time()
-            with Fleet(n_rep, models=models_spec, max_wait_ms=10.0) as fleet:
-                entries = route_jobs(fleet.url, payloads, timeout=600)
-                fst = fleet.stats()
-            wall = time.time() - t0
-            out["serve_fleet"][f"replicas_{n_rep}"] = {
-                "wall_seconds": wall,
-                "totals_match": fleet_totals(entries) == seq_totals,
-                "jobs_per_batch": fst["fleet"]["jobs_per_batch"],
-                "routed_per_replica": fst["router"]["routed_per_replica"],
-                "failovers": fst["router"]["failovers"],
-                "service_ms_p99": fst["telemetry"]["service_ms"]["p99"],
-            }
-
-        # failover drill: kill r0 once half the grid is accepted; the
-        # router ejects it and route_jobs resubmits its lost jobs to r1
-        t0 = time.time()
-        with Fleet(2, models=models_spec, max_wait_ms=200.0) as fleet:
-            drill = {}
-
-            def drive():
-                drill["entries"] = route_jobs(fleet.url, payloads, timeout=600)
-
-            th = threading.Thread(target=drive)
-            th.start()
-            want = max(1, len(grid) // 2)
-            while fleet.router.stats(refresh=False)["router"]["jobs_routed"] < want:
-                time.sleep(0.01)
-            fleet.kill_replica(0)
-            th.join()
-            fst = fleet.stats()
-        wall = time.time() - t0
-        entries = drill["entries"]
-        out["serve_fleet"]["failover"] = {
-            "wall_seconds": wall,
-            "completed": sum(e["status"] == "done" for e in entries),
-            "totals_match": fleet_totals(entries) == seq_totals,
-            "resubmits": sum(e["resubmits"] for e in entries),
-            "ejections": fst["router"]["ejections"],
-            "survivor_routed": fst["router"]["routed_per_replica"],
-        }
-        sf = out["serve_fleet"]
-        print(f"[pipeline] serve_fleet: {len(grid)} jobs — 1 replica "
-              f"{sf['replicas_1']['wall_seconds']:.1f}s, 2 replicas "
-              f"{sf['replicas_2']['wall_seconds']:.1f}s, failover drill "
-              f"{sf['failover']['completed']}/{len(grid)} done with "
-              f"{sf['failover']['resubmits']} resubmits after "
-              f"{sf['failover']['ejections']} ejection(s); totals_match="
-              f"{sf['replicas_1']['totals_match']}/"
-              f"{sf['replicas_2']['totals_match']}/"
-              f"{sf['failover']['totals_match']}", flush=True)
-
-    # --- step_layout: ring vs roll simulator state layouts ---------------
-    # Steady-state packed step throughput (timeit re-stream of a device-
-    # staged pack) at ctx_len 64. Teacher-forced rows isolate the pure
-    # sim-step state update — the traffic the ring layout attacks; the
-    # predictor rows show the end-to-end effect; the bf16 rows measure the
-    # advertised state_dtype="bfloat16" (totals stay exact teacher-forced:
-    # cycle counters are f32). The analytic traffic model rides along so
-    # the measured ratio can be compared with the roofline term.
-    from repro.core import features as Feat
-    from repro.core.simulator import SimConfig
-    from repro.runtime.roofline import sim_step_traffic
-    from repro.serving.simnet_engine import SimNetEngine
-
-    lay_traces = traces[: 4 if quick else 8]
-    lay_arrs = [Feat.trace_fields(t) for t in lay_traces]
-    lanes_each = 16  # 64+ packed lanes: the serving-shaped batch size
-    ctx = 64
-    reps = 3  # best-of: sub-second steady passes are scheduler-noisy
-
-    def steady(layout, state_dtype="float32", with_model=False):
-        scfg = SimConfig(ctx_len=ctx, layout=layout, state_dtype=state_dtype)
-        eng = SimNetEngine(
-            art.params if with_model else None,
-            art.pcfg if with_model else None,
-            scfg, cache=CompileCache(),
-        )
-        runs = [
-            eng.simulate_many(lay_arrs, n_lanes=lanes_each, chunk=128, timeit=True)
-            for _ in range(reps)
-        ]
-        r = min(runs, key=lambda x: x["seconds"])
-        return {
-            "layout": layout, "state_dtype": state_dtype,
-            "seconds": r["seconds"], "ips": r["throughput_ips"],
-            "steps_per_second": r["n_steps"] / r["seconds"],
-            "total_cycles": r["total_cycles"],  # layout exactness in plain sight
-        }
-
-    def rows(with_model):
-        rs = [steady(lay, sd, with_model) for lay, sd in
-              (("roll", "float32"), ("ring", "float32"), ("ring", "bfloat16"))]
-        for r in rs:
-            r["speedup_vs_roll"] = rs[0]["seconds"] / r["seconds"]
-        return rs
-
-    tf_rows = rows(with_model=False)
-    pred_rows = rows(with_model=True)
-    out["step_layout"] = {
-        "ctx_len": ctx,
-        "n_workloads": len(lay_arrs),
-        "lanes_per_workload": lanes_each,
-        "teacher_forced": tf_rows,
-        "predictor_c3": pred_rows,
-        "traffic_model": sim_step_traffic(ctx, lanes_each * len(lay_arrs)),
-        "traffic_model_bf16": sim_step_traffic(
-            ctx, lanes_each * len(lay_arrs), state_dtype_bytes=2
-        ),
-    }
-    print(f"[pipeline] step_layout ctx{ctx}: teacher-forced ring "
-          f"{tf_rows[1]['speedup_vs_roll']:.2f}x roll "
-          f"(bf16 {tf_rows[2]['speedup_vs_roll']:.2f}x), predictor ring "
-          f"{pred_rows[1]['speedup_vs_roll']:.2f}x roll", flush=True)
-    for sec in ("contention", "chaos"):  # those steps may have run first
-        if sec in prior:
-            out[sec] = prior[sec]
-    _save_json("packed_throughput.json", out)
-
-
 def step_contention(data, quick):
     """Shared-resource contention (multicore DES): does SimNet track co-run
     latencies? Trains nothing new for the solo baseline — the zoo's c3_hybrid
@@ -668,11 +271,11 @@ def step_contention(data, quick):
     every co-run trace (mixed lengths, mixed retire widths, mixed lane
     counts) through ONE teacher-forced `simulate_many` and checks totals are
     bit-identical to per-trace simulation (heterogeneous-lane correctness).
-    Merges a `contention` section into packed_throughput.json."""
+    Merges a `contention` section into contention_chaos.json."""
     from repro.des.multicore import contention_report
     from repro.des.workloads import MULTICORE_MIXES, get_mix
 
-    path = ART / "packed_throughput.json"
+    path = ART / "contention_chaos.json"
     prior = json.loads(path.read_text()) if path.exists() else {}
     if "contention" in prior:
         return
@@ -762,7 +365,7 @@ def step_contention(data, quick):
     print(f"[pipeline] contention: c3 solo {models['c3_solo']['avg_err']:.4f} "
           f"-> augmented {models['c3_contention']['avg_err']:.4f}, "
           f"pack totals_match={totals_match}", flush=True)
-    _save_json("packed_throughput.json", prior)
+    _save_json("contention_chaos.json", prior)
 
 
 def step_chaos(quick):
@@ -773,10 +376,10 @@ def step_chaos(quick):
     them. The drill's own invariants (survivors bit-identical to a
     fault-free baseline, zero jobs lost, crashed replica restarted and
     readmitted, corrupt model breaker-isolated) ride in the ``checks``
-    maps. Merges a `chaos` section into packed_throughput.json."""
+    maps. Merges a `chaos` section into contention_chaos.json."""
     from repro.serving.chaos import run_chaos_fleet, run_chaos_single
 
-    path = ART / "packed_throughput.json"
+    path = ART / "contention_chaos.json"
     prior = json.loads(path.read_text()) if path.exists() else {}
     if "chaos" in prior:
         return
@@ -791,7 +394,7 @@ def step_chaos(quick):
           f"({fleet['wall_seconds']:.1f}s, "
           f"{fleet['supervisor'].get('restarts_total', 0)} supervised "
           f"restart(s), {fleet['resubmits']} resubmits)", flush=True)
-    _save_json("packed_throughput.json", prior)
+    _save_json("contention_chaos.json", prior)
 
 
 def step_a64fx(quick):
@@ -847,18 +450,13 @@ def main():
     print(f"[pipeline] dataset {data['dataset']['train_x'].shape} {time.time()-t0:.0f}s", flush=True)
     train_zoo(data, args.quick, skip_missing=args.eval_only)
     steps = args.steps.split(",") if args.steps != "all" else [
-        "table4", "fig56", "fig7", "fig89", "throughput", "contention",
-        "chaos", "table5", "a64fx"]
+        "table4", "fig56", "fig7", "contention", "chaos", "table5", "a64fx"]
     if "table4" in steps:
         step_table4(data, args.quick)
     if "fig56" in steps:
         step_fig56(data, args.quick)
     if "fig7" in steps:
         step_fig7(data, args.quick)
-    if "fig89" in steps:
-        step_fig89(data, args.quick)
-    if "throughput" in steps:
-        step_throughput(data, args.quick)
     if "contention" in steps:
         step_contention(data, args.quick)
     if "chaos" in steps:

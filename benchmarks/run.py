@@ -12,12 +12,15 @@ Sections:
   table4     ML model zoo: prediction error / simulation error / MFlops
   fig5_6     per-benchmark CPIs + phase-level accuracy
   fig7       parallel-simulation error vs sub-trace size
-  fig8_9_10  simulation throughput, device scaling + training amortization
-  throughput batched multi-workload engine: packed vs sequential instr/s
   contention multicore co-run traces: solo vs contention-augmented training
+  chaos      seeded fault injection, integrity guards, self-healing
   table5     design-space relative accuracy (branch predictors, L2 size)
   a64fx      second processor configuration (paper §4.1)
-  roofline   dry-run roofline summary (full tables: python -m benchmarks.roofline)
+  roofline   dry-run roofline summary and the compiled HLO's collective
+             count (full tables: python -m benchmarks.roofline)
+
+Speed is measured on the chip by `bench/run.py`; its records are
+PERF.md and PERF_LEDGER.jsonl.
 """
 from __future__ import annotations
 
@@ -100,137 +103,8 @@ def _loadd(name):
     return json.loads(p.read_text()) if p.exists() else None
 
 
-def fig8_9_10():
-    data = _load("fig89_throughput.json")
-    _sec("Figures 8–10 — simulation throughput & scaling")
-    if data is None:
-        print("(artifacts missing)")
-        return
-    print(f"  reference DES: {data['des_ips']:.0f} instr/s ({data['hardware']})")
-    for p in data["points"]:
-        speedup = p["ips"] / data["des_ips"]
-        print(f"  SimNet lanes {p['lanes']:4d}: {p['ips']:9.0f} instr/s  ({speedup:5.1f}x DES)")
-        CSV_ROWS.append((f"fig8/lanes{p['lanes']}", 1e6 / p["ips"], speedup))
-    sim_mp = _loadd("simnet-c3__simulate_64k__multipod.json")
-    if sim_mp:
-        print(f"  dry-run simnet-c3 on 512 devices: {sim_mp['collectives']['total_count']:.0f} "
-              "collective ops in the compiled HLO (paper §3.3: no inter-device communication)")
-
-
-def throughput():
-    data = _load("packed_throughput.json")
-    _sec("Batched multi-workload engine — packed vs sequential throughput")
-    if data is None or "packed" not in data:
-        print("(artifacts missing — run `python -m benchmarks.pipeline`)")
-        return
-    seq, packed = data["sequential"], data["packed"]
-    print(f"  workloads: {data['n_workloads']} × {data['lanes_per_workload']} lanes each")
-    print(f"  sequential (one jitted call per workload): {seq['ips']:12.0f} instr/s "
-          f"({seq['n_instructions']} instrs, {seq['wall_seconds']:.2f}s wall: W compiles + W runs)")
-    print(f"  packed     (all workloads in one scan):    {packed['ips']:12.0f} instr/s "
-          f"({packed['n_instructions']} instrs, {packed['wall_seconds']:.2f}s wall: 1 compile + 1 run)")
-    print(f"  whole-sweep wall-clock speedup: {data['speedup_wall']:.2f}x "
-          f"(steady-state, compiled vs compiled: {data['speedup_steady']:.2f}x)")
-    CSV_ROWS.append(("throughput/sequential", 1e6 / seq["ips"], None))
-    CSV_ROWS.append(("throughput/packed", 1e6 / packed["ips"], data["speedup_wall"]))
-    for side in ("sequential", "packed"):
-        c = data[side].get("cache")
-        if c:
-            print(f"  {side} compile cache: {c['misses']} compiles "
-                  f"({c['compile_seconds']:.2f}s), {c['hits']} hits")
-            CSV_ROWS.append((f"throughput/{side}_compile_s", 0.0, c["compile_seconds"]))
-    zoo = data.get("serve_zoo")
-    if zoo:
-        c = zoo["cache"]
-        print(f"  SimServe zoo sweep: {zoo['n_jobs']} jobs over "
-              f"{len(zoo['models'])} resident models × {zoo['n_workloads']} workloads "
-              f"in {zoo['wall_seconds']:.1f}s ({zoo['batches']} shared batches)")
-        print(f"    compile cache: {c['misses']} misses / {c['hits']} hits, "
-              f"{c['compile_seconds']:.2f}s total compile "
-              f"(executable reuse — wave 2 pays zero compiles)")
-        for i, wave in enumerate(zoo.get("waves", [])):
-            fc = wave["per_model_first_call_seconds"]
-            rng = (f", per-model first_call {min(fc.values()):.2f}–"
-                   f"{max(fc.values()):.2f}s" if fc else " (no resident models)")
-            print(f"    wave {i}: {wave['wall_seconds']:6.2f}s wall{rng}")
-        CSV_ROWS.append(("serve_zoo/cache_hits", 0.0, c["hits"]))
-        CSV_ROWS.append(("serve_zoo/cache_misses", 0.0, c["misses"]))
-        CSV_ROWS.append(("serve_zoo/compile_seconds", 0.0, c["compile_seconds"]))
-    sa = data.get("serve_async")
-    if sa:
-        seq_s, asy = sa["sequential"], sa["async"]
-        print(f"  SimServe async drain loop: {sa['n_jobs']} jobs from "
-              f"{sa['n_clients']} client threads over {len(sa['models'])} models")
-        print(f"    sequential one-batch-per-job: {seq_s['batches']} batches "
-              f"in {seq_s['wall_seconds']:.1f}s")
-        print(f"    background loop:              {asy['batches']} batches "
-              f"({asy['jobs_per_batch']:.1f} jobs/batch) in "
-              f"{asy['wall_seconds']:.1f}s — totals "
-              f"{'bit-identical' if sa['totals_match'] else 'MISMATCH'}")
-        CSV_ROWS.append(("serve_async/seq_wall_s", 0.0, seq_s["wall_seconds"]))
-        CSV_ROWS.append(("serve_async/async_wall_s", 0.0, asy["wall_seconds"]))
-        CSV_ROWS.append(("serve_async/jobs_per_batch", 0.0, asy["jobs_per_batch"]))
-        CSV_ROWS.append(("serve_async/totals_match", 0.0, float(sa["totals_match"])))
-    sh = data.get("serve_http")
-    if sh:
-        print(f"  SimServe over HTTP: {sh['n_jobs']} jobs from "
-              f"{sh['n_clients']} wire clients over {len(sh['models'])} models")
-        print(f"    {sh['batches']} batches ({sh['jobs_per_batch']:.1f} "
-              f"jobs/batch) in {sh['wall_seconds']:.1f}s — p99 service "
-              f"{sh['service_ms_p99']:.0f} ms, p99 queue wait "
-              f"{sh['queue_wait_ms_p99']:.0f} ms, totals "
-              f"{'bit-identical' if sh['totals_match'] else 'MISMATCH'}")
-        CSV_ROWS.append(("serve_http/wall_s", 0.0, sh["wall_seconds"]))
-        CSV_ROWS.append(("serve_http/jobs_per_batch", 0.0, sh["jobs_per_batch"]))
-        CSV_ROWS.append(("serve_http/service_ms_p99", 0.0, sh["service_ms_p99"]))
-        CSV_ROWS.append(("serve_http/totals_match", 0.0, float(sh["totals_match"])))
-    sf = data.get("serve_fleet")
-    if sf:
-        print(f"  SimServe fleet: {sf['n_jobs']} jobs through the router "
-              f"over replica subprocesses ({len(sf['models'])} models)")
-        for lane in ("replicas_1", "replicas_2"):
-            r = sf.get(lane)
-            if not r:
-                continue
-            print(f"    {lane:14s} {r['wall_seconds']:6.1f}s wall "
-                  f"(startup + cold per-replica compiles), "
-                  f"{r['jobs_per_batch']:.1f} jobs/batch, totals "
-                  f"{'bit-identical' if r['totals_match'] else 'MISMATCH'}")
-            CSV_ROWS.append((f"serve_fleet/{lane}_wall_s", 0.0,
-                             r["wall_seconds"]))
-            CSV_ROWS.append((f"serve_fleet/{lane}_totals_match", 0.0,
-                             float(r["totals_match"])))
-        fo = sf.get("failover")
-        if fo:
-            print(f"    failover drill: {fo['completed']}/{sf['n_jobs']} done "
-                  f"after killing a replica mid-run — {fo['resubmits']} "
-                  f"resubmit(s), {fo['ejections']} ejection(s), totals "
-                  f"{'bit-identical' if fo['totals_match'] else 'MISMATCH'}")
-            CSV_ROWS.append(("serve_fleet/failover_completed", 0.0,
-                             fo["completed"]))
-            CSV_ROWS.append(("serve_fleet/failover_totals_match", 0.0,
-                             float(fo["totals_match"])))
-    lay = data.get("step_layout")
-    if lay:
-        print(f"  step layouts (ring vs roll state traffic, ctx_len "
-              f"{lay['ctx_len']}, {lay['n_workloads']}×{lay['lanes_per_workload']} lanes):")
-        for mode in ("teacher_forced", "predictor_c3"):
-            for row in lay.get(mode, []):
-                tag = f"{mode}/{row['layout']}-{row['state_dtype']}"
-                print(f"    {tag:34s} {row['ips']:10.0f} instr/s "
-                      f"({row['seconds']:6.2f}s steady, "
-                      f"{row['speedup_vs_roll']:.2f}x roll)")
-                CSV_ROWS.append((f"step_layout/{tag}", 1e6 / row["ips"],
-                                 row["speedup_vs_roll"]))
-        tm = lay.get("traffic_model")
-        if tm:
-            print(f"    roofline traffic model: roll {tm['roll_bytes_per_step']/1e6:.2f} "
-                  f"MB/step vs ring {tm['ring_bytes_per_step']/1e6:.2f} MB/step "
-                  f"→ {tm['ratio']:.1f}x less queue-state HBM traffic")
-
-
 def contention():
-    data = _load("packed_throughput.json")
+    data = _load("contention_chaos.json")
     _sec("Contention — multicore DES co-run traces: solo vs augmented training")
     ct = (data or {}).get("contention")
     if ct is None:
@@ -268,7 +142,7 @@ def contention():
 
 
 def chaos():
-    data = _load("packed_throughput.json")
+    data = _load("contention_chaos.json")
     _sec("Chaos — seeded fault injection, integrity guards, self-healing")
     ch = (data or {}).get("chaos")
     if ch is None:
@@ -355,6 +229,10 @@ def roofline_summary():
         print(summary("pod"))
     except Exception as e:
         print(f"(unavailable: {e})")
+    sim_mp = _loadd("simnet-c3__simulate_64k__multipod.json")
+    if sim_mp:
+        print(f"  dry-run simnet-c3 on 512 devices: {sim_mp['collectives']['total_count']:.0f} "
+              "collective ops in the compiled HLO (paper §3.3: no inter-device communication)")
 
 
 def main() -> None:
@@ -364,8 +242,6 @@ def main() -> None:
     table4()
     fig5_6()
     fig7()
-    fig8_9_10()
-    throughput()
     contention()
     chaos()
     table5()

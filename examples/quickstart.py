@@ -43,7 +43,7 @@ def main():
     print("== 3. reload + ML-simulate a held-out benchmark ==")
     sn = SimNet.from_artifact(ARTIFACT)  # what a later process would do
     tr = api.generate_traces(["sim_loop"], T_EVAL)[0]
-    res = sn.simulate(tr, n_lanes=8, timeit=True)  # SimResult (1-workload pack)
+    res = sn.simulate(tr, n_lanes=8)  # SimResult (1-workload pack)
     w = res[0]
     print(f"  DES CPI {w.des_cpi:.3f} vs SimNet CPI {w.cpi:.3f} "
           f"(error {100*w.cpi_error:.1f}%)")

@@ -56,7 +56,7 @@ def main():
     print(f"  {args.n} instructions in {t_hist:.1f}s ({args.n/t_hist:.0f} IPS)")
 
     print(f"== parallel ML simulation: {args.lanes} lanes ==")
-    res = sn.simulate(trace, n_lanes=args.lanes, chunk=512, timeit=True)
+    res = sn.simulate(trace, n_lanes=args.lanes, chunk=512)
     w = res[0]
     print(f"  SimNet: {w.total_cycles:.0f} cycles, CPI {w.cpi:.3f}, "
           f"{res.throughput_ips:.0f} instr/s")

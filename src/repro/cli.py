@@ -23,7 +23,6 @@ compose with jq / CI checks.
             SIGTERM/SIGINT — what `repro fleet` spawns N of)
   fleet     spawn N replica subprocesses + the router tier over them,
             round-trip a job file through the router as a real client
-  bench     packed-vs-sequential engine microbenchmark
 
 Train once, simulate anywhere:
 
@@ -52,7 +51,6 @@ from __future__ import annotations
 import argparse
 import json
 import sys
-import time
 from pathlib import Path
 
 from repro.core import api
@@ -176,7 +174,7 @@ def _session(args) -> SimNet:
 def cmd_simulate(args) -> int:
     sn = _session(args)
     traces = _gen_traces(args.bench, args.n, args.o3, args.cache_dir)
-    res = sn.simulate_many(traces, n_lanes=args.lanes, timeit=args.timeit)
+    res = sn.simulate_many(traces, n_lanes=args.lanes)
     _emit({"artifact": args.artifact, "result": res.to_dict()})
     return 0
 
@@ -517,50 +515,6 @@ def cmd_chaos(args) -> int:
     return 0 if out["ok"] else 1
 
 
-def cmd_bench(args) -> int:
-    """Packed-vs-sequential: W workloads through one packed engine call vs
-    one freshly-compiled engine per workload (the pre-packing behaviour —
-    each sequential call gets its own COLD cache, otherwise it would
-    free-ride on the shared executable cache it predates)."""
-    import dataclasses
-
-    from repro.serving.compile_cache import CompileCache
-
-    n = 3000 if args.quick else args.n
-    if args.multicore or args.mix:
-        # co-run traces: genuinely heterogeneous lane dynamics in the pack
-        traces = api.generate_corun_traces(
-            args.mix or "mix_stream_chase", n, o3=O3_CONFIGS[args.o3],
-            n_cores=args.multicore, cache_dir=args.cache_dir,
-        )
-    else:
-        names = args.bench or ["mlb_stream", "mlb_compute", "sim_loop", "mlb_branchy"]
-        traces = _gen_traces(names, n, args.o3, args.cache_dir)
-    art = SimNet.from_artifact(args.artifact).artifact if args.artifact else None
-
-    def fresh():
-        kw = {"cache": CompileCache(), "use_kernel": bool(args.use_kernel)}
-        if args.layout:
-            base = art.sim_cfg if art else SimConfig()
-            kw["sim_cfg"] = dataclasses.replace(base, layout=args.layout)
-        return SimNet(art, **kw) if art else SimNet(**kw)
-
-    t0 = time.time()
-    seq = [fresh().simulate(t, n_lanes=args.lanes, timeit=False) for t in traces]
-    seq_wall = time.time() - t0
-    packed = fresh().simulate_many(traces, n_lanes=args.lanes)
-    _emit({
-        "n_workloads": len(traces),
-        "lanes_per_workload": args.lanes,
-        "sequential": {"wall_seconds": seq_wall,
-                       "ips": sum(r.total_instructions for r in seq) / seq_wall},
-        "packed": {"wall_seconds": packed.first_call_seconds,
-                   "ips": packed.throughput_ips},
-        "speedup_wall": seq_wall / packed.first_call_seconds,
-    })
-    return 0
-
-
 def cmd_lint(args) -> int:
     """Domain static analysis (src/repro/analysis): lock discipline,
     compile-cache-key completeness, determinism, exception hygiene.
@@ -671,8 +625,6 @@ def build_parser() -> argparse.ArgumentParser:
     _engine_flags(p)
     p.add_argument("--artifact", default=None,
                    help="PredictorArtifact directory (omit for teacher-forced replay)")
-    p.add_argument("--timeit", action="store_true",
-                   help="measure steady-state throughput (second compiled pass)")
     p.set_defaults(fn=cmd_simulate, bench_default=["sim_loop"])
 
     p = sub.add_parser("sweep", help="design-space sweep in one packed call")
@@ -780,13 +732,6 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--out", default=None,
                    help="also write the JSON report here")
     p.set_defaults(fn=cmd_chaos)
-
-    p = sub.add_parser("bench", help="packed vs sequential throughput microbench")
-    _common(p, n_default=6000)
-    _engine_flags(p)
-    _multicore_flags(p)
-    p.add_argument("--artifact", default=None)
-    p.set_defaults(fn=cmd_bench)
 
     p = sub.add_parser(
         "lint",

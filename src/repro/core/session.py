@@ -336,7 +336,6 @@ class SimNet:
         *,
         sim_cfgs: Union[SimConfig, Sequence[SimConfig], None] = None,
         chunk: Optional[int] = None,
-        timeit: bool = False,
     ) -> SimResult:
         """Pack all workloads onto one lane axis and run THE simulation path:
         submit every workload to the session's `SimServe` and drain — the
@@ -346,8 +345,7 @@ class SimNet:
 
         ``traces`` are labelled `des.trace.Trace` objects (DES comparison
         fields filled in) or raw trace_arrays dicts. ``n_lanes`` and
-        ``sim_cfgs`` may be per-workload. timeit=True re-streams the pack
-        once compiled so throughput is steady-state.
+        ``sim_cfgs`` may be per-workload.
         """
         traces = list(traces)
         if not traces:
@@ -366,7 +364,7 @@ class SimNet:
             for i, (t, ln, cfg) in enumerate(zip(traces, lanes, cfgs)):
                 handles.append(self.service.submit(
                     t, self.model_id,
-                    n_lanes=int(ln), sim_cfg=cfg, timeit=timeit,
+                    n_lanes=int(ln), sim_cfg=cfg,
                     chunk=chunk or self.chunk,
                     name=getattr(t, "name", None) or f"workload{i}",
                 ))
@@ -418,16 +416,9 @@ class SimNet:
         n_lanes: int = 16,
         *,
         chunk: Optional[int] = None,
-        timeit: bool = False,
     ) -> SimResult:
-        """Single-workload simulation = the 1-workload pack (same path).
-
-        timeit=True re-streams a device-staged copy of the whole pack for
-        steady-state throughput — device memory O(trace), so keep it for
-        benchmark-sized traces; the default streams O(chunk)."""
-        return self.simulate_many(
-            [trace], n_lanes=n_lanes, chunk=chunk, timeit=timeit
-        )
+        """Single-workload simulation = the 1-workload pack (same path)."""
+        return self.simulate_many([trace], n_lanes=n_lanes, chunk=chunk)
 
     def sweep(
         self,
@@ -435,7 +426,6 @@ class SimNet:
         n_lanes: Union[int, Sequence[int]] = 8,
         *,
         chunk: Optional[int] = None,
-        timeit: bool = False,
     ) -> SweepResult:
         """Design-space sweep: every point's workloads ride ONE packed call.
 
@@ -463,6 +453,6 @@ class SimNet:
             cfgs.append(cfg if cfg is not None else self.sim_cfg)
         res = self.simulate_many(
             traces, n_lanes=n_lanes,
-            sim_cfgs=cfgs if any_cfg else None, chunk=chunk, timeit=timeit,
+            sim_cfgs=cfgs if any_cfg else None, chunk=chunk,
         )
         return SweepResult(labels=tuple(labels), result=res)

@@ -17,7 +17,7 @@ logical recency-ordered queue:
     traffic for the wide feat/addr planes drops from O(L·Q·F) writes to
     an O(L·F) slot write (the latency planes are still read in full by
     retirement, and the small (L, Q) bookkeeping planes still update in
-    place — `runtime.roofline.sim_step_traffic` models the ~16× net).
+    place).
   "roll" — the original shift-push layout (slot 0 = physically newest;
     every plane moves one slot per step). Kept as the exactness
     reference: the ring step reproduces `_retire`'s recency-ordered

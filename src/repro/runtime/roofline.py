@@ -79,43 +79,6 @@ def roofline(flops_per_device: float, bytes_per_device: float, collective_bytes:
     )
 
 
-def sim_step_traffic(
-    ctx_len: int,
-    n_lanes: int,
-    state_dtype_bytes: int = 4,
-    n_feat: int = 41,
-    n_addr: int = 5,
-) -> Dict[str, float]:
-    """Analytic HBM bytes per packed sim step for the simulator queue
-    state, per layout — the term the ring buffer attacks.
-
-    roll: every plane is read and rewritten each step (the shift-push
-      moves all Q slots): 2 · L · Q · bytes(entry).
-    ring: the feat/addr static planes are written at ONE slot and never
-      read by the state update; the exec/store latency planes are still
-      READ in full (retirement readiness compares) but written at one
-      slot; the small bookkeeping planes (resid + valid/in_mw/is_store
-      flags) still move in full:
-      L · Q · (2 · bytes(bookkeeping) + bytes(latency)) + L · bytes(slot).
-
-    Model-input assembly (predictor mode) reads O(L·Q·F) either way —
-    unless the fused sim-step kernel assembles it in VMEM, which removes
-    that read's round-trip too (see kernels/fused_step.py).
-    """
-    static = n_feat * state_dtype_bytes + n_addr * 4  # write-only in ring
-    lat = 2 * 4  # exec/store f32: full read, slot write
-    book = 4 + 3 * 1  # resid f32 + valid/in_mw/is_store bools: full r/w
-    roll = 2.0 * n_lanes * ctx_len * (static + lat + book)
-    ring = n_lanes * ctx_len * (2.0 * book + lat) + n_lanes * (static + lat)
-    return {
-        "roll_bytes_per_step": roll,
-        "ring_bytes_per_step": ring,
-        "ratio": roll / ring,
-        "roll_memory_s": roll / HBM_BW,
-        "ring_memory_s": ring / HBM_BW,
-    }
-
-
 def model_flops(cfg, shape, n_devices: int) -> Dict[str, float]:
     """Useful-work model FLOPs: 6·N·D train, 2·N·D per decode step (N =
     active params). Returned per device, for the MODEL/HLO ratio."""

@@ -128,7 +128,7 @@ class BatchReport:
     # the bytes it holds
     pack_buffer: Dict[str, int] = dataclasses.field(default_factory=dict)
     # host seconds of each phase, timed by its `telemetry.span`: the jobs'
-    # trace fields at submit (`F.trace_fields`), then the engine's first pass
+    # trace fields at submit (`F.trace_fields`), then the engine's phases
     featurize_seconds: float = 0.0
     pack_seconds: float = 0.0
     stage_seconds: float = 0.0  # host-to-device puts and chunk enqueues
@@ -150,7 +150,6 @@ class _Job:
     name: str
     n_lanes: int
     sim_cfg: Optional[SimConfig]
-    timeit: bool
     chunk: Optional[int]
     priority: int = 0
     deadline_ms: Optional[float] = None
@@ -459,7 +458,6 @@ class SimServe:
         n_lanes: int = 8,
         sim_cfg: Optional[SimConfig] = None,
         name: Optional[str] = None,
-        timeit: bool = False,
         chunk: Optional[int] = None,
         priority: int = 0,
         deadline_ms: Optional[float] = None,
@@ -571,7 +569,6 @@ class SimServe:
                     name=name or getattr(trace, "name", None) or f"job{job_id}",
                     n_lanes=int(n_lanes),
                     sim_cfg=sim_cfg,
-                    timeit=timeit,
                     chunk=chunk,
                     priority=int(priority),
                     deadline_ms=None if deadline_ms is None else float(deadline_ms),
@@ -605,11 +602,11 @@ class SimServe:
                     return True
         return False
 
-    def _group_key(self, job: _Job):
-        """Jobs sharing a key may ride one packed scan: same resident
-        model and same timeit mode. (The non-per-lane SimConfig fields are
-        already guaranteed by submit() to match the resident engine's.)"""
-        return (job.model_id, job.timeit)
+    def _group_key(self, job: _Job) -> str:
+        """Jobs sharing a key may ride one packed scan: the same resident
+        model. (The non-per-lane SimConfig fields are already guaranteed by
+        submit() to match the resident engine's.)"""
+        return job.model_id
 
     def _effective_priority(self, job: _Job, now: float) -> int:
         """Base priority plus the aging bonus (+1 per ``aging_ms``
@@ -634,7 +631,7 @@ class SimServe:
         return (math.inf if job.deadline_ms is None
                 else job.submit_t + job.deadline_ms / 1000.0)
 
-    def _take_batch(self) -> Tuple[Optional[Tuple], List[_Job]]:
+    def _take_batch(self) -> Tuple[Optional[str], List[_Job]]:
         """Atomically pop the next batch, QoS-aware.
 
         First, every queued job whose deadline already passed is failed
@@ -648,7 +645,7 @@ class SimServe:
         FIFO) up to the queue-depth-aware lane budget."""
         now = self._clock()
         expired: List[_Job] = []
-        key: Optional[Tuple] = None
+        key: Optional[str] = None
         batch: List[_Job] = []
         with self._qlock:
             if any(j.deadline_ms is not None for j in self._pending):
@@ -672,7 +669,7 @@ class SimServe:
                                key=lambda j: (self._deadline_at(j), j.job_id))
                     key = self._group_key(lead)
                 else:
-                    keys: List[Tuple] = []
+                    keys: List[str] = []
                     for job in top_jobs:
                         k = self._group_key(job)
                         if k not in keys:
@@ -695,7 +692,7 @@ class SimServe:
                 taken = {id(j) for j in batch}
                 self._pending = [j for j in self._pending
                                  if id(j) not in taken]
-                self._last_model = key[0]
+                self._last_model = key
         for job in expired:
             waited_ms = (now - job.submit_t) * 1000.0
             job.error = DeadlineExceeded(
@@ -710,15 +707,13 @@ class SimServe:
                       deadline_ms=job.deadline_ms)
         return key, batch
 
-    def _next_group(self, keys: Sequence[Tuple]) -> Tuple:
-        """Round-robin across models: the waiting group whose model id is
-        the cyclic successor of the last-served one (queue order breaks
-        ties between groups of the same model)."""
+    def _next_group(self, keys: Sequence[str]) -> str:
+        """Round-robin across models: the waiting model id that is the
+        cyclic successor of the last-served one."""
         if self._last_model is None:
             return keys[0]
-        models = sorted({k[0] for k in keys})
-        nxt = next((m for m in models if m > self._last_model), models[0])
-        return next(k for k in keys if k[0] == nxt)
+        models = sorted(keys)
+        return next((m for m in models if m > self._last_model), models[0])
 
     def drain(self) -> List[BatchReport]:
         """Run every pending job on the calling thread. Each iteration
@@ -737,7 +732,7 @@ class SimServe:
             if key is None:
                 break
             try:
-                reports.append(self._run_batch(key[0], batch))
+                reports.append(self._run_batch(key, batch))
             except BaseException as e:
                 # the batch's jobs already left the queue — pin the error on
                 # each so result() raises instead of returning None, then
@@ -747,7 +742,7 @@ class SimServe:
                 for job in batch:
                     job.error = e
                     job.done_evt.set()
-                self.registry.breaker(key[0]).record_failure()
+                self.registry.breaker(key).record_failure()
                 if isinstance(e, NumericError):
                     # numeric guard: the engine refused NaN/Inf totals —
                     # count loudly; silent CPI corruption is the one
@@ -755,12 +750,12 @@ class SimServe:
                     with self._qlock:
                         self._jobs_failed_numeric += len(batch)
                     log_event("batch.numeric_failure", level=logging.ERROR,
-                              model=key[0],
+                              model=key,
                               bad_workloads=e.bad_workloads,
                               job_ids=[j.job_id for j in batch],
                               correlation_ids=[j.corr_id for j in batch])
                 log_event("batch.failed", level=logging.ERROR,
-                          model=key[0], job_ids=[j.job_id for j in batch],
+                          model=key, job_ids=[j.job_id for j in batch],
                           correlation_ids=[j.corr_id for j in batch],
                           error=repr(e))
                 raise
@@ -779,14 +774,13 @@ class SimServe:
             cfgs = [j.sim_cfg or engine.sim_cfg for j in jobs]
             cap = min(j.chunk or self.chunk for j in jobs)
             chunk = chunk_bucket(max_packed_steps(fields, lanes), cap)
-            timeit = jobs[0].timeit
 
             def dispatch():
                 # chaos seam: delay_ms simulates a hung dispatch (watchdog
                 # prey), fail an engine that detonates mid-batch
                 faults.fire("batch.execute")
                 return engine.simulate_many(
-                    fields, n_lanes=lanes, chunk=chunk, cfgs=cfgs, timeit=timeit
+                    fields, n_lanes=lanes, chunk=chunk, cfgs=cfgs
                 )
 
             res = self._dispatch_guarded(model_id, jobs, dispatch)

@@ -10,16 +10,16 @@ The lane axis is multi-workload: ``simulate_many`` packs lanes from many
 workloads × SimConfigs into one sharded scan (per-lane workload ids,
 validity masks for ragged trace lengths, per-lane retire width / context
 capacity) and streams arbitrarily long traces through chunked jitted calls
-with donated state buffers. ``simulate`` is the single-workload special
-case of the same path.
+with donated state buffers.
 
 Since the SimServe redesign the chunk program is **resident**: executables
 come from the process-wide `serving.compile_cache` keyed by architecture
-(never weights — params are a call argument), lane counts round up to
-power-of-two buckets with dead lanes masked, and the packed trace chunks
-are staged device-side once so a ``timeit`` re-stream measures the scan,
-not host transfers. Two engines around two models of the same kind share
-one compiled program; a fresh engine on a warm cache pays zero compiles.
+(never weights — params are a call argument), and lane counts round up to
+power-of-two buckets with dead lanes masked. Each call packs, puts the
+lane configs and the state, then puts and enqueues one chunk at a time
+(device memory stays O(chunk)) and waits once for the totals. Two engines
+around two models of the same kind share one compiled program; a fresh
+engine on a warm cache pays zero compiles.
 
 ``input_specs()`` / ``lower()`` make the engine dry-runnable on the
 production mesh alongside the LM pool (simnet-c3 / simnet-rb7 arch cells).
@@ -256,7 +256,6 @@ class SimNetEngine:
         n_lanes: Union[int, Sequence[int]] = 8,
         chunk: int = 1024,
         cfgs: Union[SimConfig, Sequence[SimConfig], None] = None,
-        timeit: bool = False,
     ) -> dict:
         """Simulate many workloads (each a `features.trace_fields` dict) in
         one packed lane batch, streaming the time axis through chunked calls
@@ -264,25 +263,22 @@ class SimNetEngine:
 
         The lane axis is bucketed to the next power of two (dead lanes are
         fully masked and contribute nothing), so nearby lane counts reuse
-        one executable. timeit=True streams the device-staged pack a second
-        time and reports steady-state throughput from that pass; the
-        one-shot compile(or cache-hit)+run cost stays in
-        ``first_call_seconds`` either way.
+        one executable. ``seconds`` times the stream and the wait;
+        ``first_call_seconds`` also holds the pack and the compile (or
+        cache hit).
 
-        The first pass's host phases come back as ``pack_seconds``,
-        ``stage_seconds`` (host-to-device puts and chunk enqueues),
-        ``device_wait_seconds`` and ``results_seconds``, each also a
-        ``simnet.*`` span on the profiler's timeline; ``pack_buffer`` holds
-        the host pack buffer's reuses and allocations in this call and the
-        bytes it holds."""
+        The host phases come back as ``pack_seconds``, ``stage_seconds``
+        (host-to-device puts and chunk enqueues), ``device_wait_seconds``
+        and ``results_seconds``, each also a ``simnet.*`` span on the
+        profiler's timeline; ``pack_buffer`` holds the host pack buffer's
+        reuses and allocations in this call and the bytes it holds."""
         with self._pack_lock:
-            return self._simulate_many(fields_list, n_lanes, chunk, cfgs, timeit)
+            return self._simulate_many(fields_list, n_lanes, chunk, cfgs)
 
-    def _simulate_many(self, fields_list, n_lanes, chunk, cfgs, timeit) -> dict:
+    def _simulate_many(self, fields_list, n_lanes, chunk, cfgs) -> dict:
         t_start = time.perf_counter()
         cache_before = self.cache.counters()
-        # host seconds of each phase of the first pass, one span each on
-        # the profiler's timeline; the timeit re-run adds nothing to them
+        # host seconds of each phase, one span each on the profiler's timeline
         phases = dict.fromkeys(
             ("pack_seconds", "stage_seconds", "device_wait_seconds", "results_seconds"), 0.0
         )
@@ -307,49 +303,30 @@ class SimNetEngine:
             self._stage_params()
             exe = self.executable(packed.n_lanes, chunk)
 
-        # per-lane configs go device-side once; trace chunks stream through
-        # one staged buffer at a time (device memory stays O(chunk)) —
-        # except under timeit, where the WHOLE pack is staged up front so
-        # the timed re-stream measures the scan, not host→device transfers
-        # (timeit therefore holds the pack device-resident: use it on
-        # benchmark-sized packs, not unbounded traces)
-        put = (
-            (lambda x, sh: jax.device_put(x, sh))
-            if self.mesh is not None else (lambda x, sh: jnp.asarray(x))
-        )
-        xs_sh = chunk_shardings(self.mesh) if self.mesh is not None else None
-        lane_sh = lane_sharding(self.mesh) if self.mesh is not None else None
-        st_sh = state_shardings(self.mesh) if self.mesh is not None else None
+        mesh = self.mesh
+        xs_sh = chunk_shardings(mesh) if mesh is not None else {}
+        lane_sh = lane_sharding(mesh) if mesh is not None else None
 
-        def stage(c):
-            return {k: put(v[c], xs_sh[k] if xs_sh else None)
-                    for k, v in packed.xs.items()}
+        def put(x, sh):
+            return jnp.asarray(x) if sh is None else jax.device_put(x, sh)
 
-        chunks = range(packed.n_chunks)
+        # the puts and the chunk enqueues: the host returns before the
+        # device has run them
         with span("simnet.stage", phases, "stage_seconds"):
-            staged = [stage(c) for c in chunks] if timeit else None
             rw = put(np.asarray(packed.retire_width), lane_sh)
             lc = put(np.asarray(packed.lane_ctx), lane_sh)
-
-        def one_pass(into):
-            t0 = time.perf_counter()
-            # the puts and the chunk enqueues: the host returns before the
-            # device has run them
-            with span("simnet.stage", into, "stage_seconds"):
-                state = init_state(packed.n_lanes, self.sim_cfg)
-                if st_sh is not None:
-                    state = jax.device_put(state, st_sh)
-                for xs in staged if staged is not None else (stage(c) for c in chunks):
-                    state = exe(self.params, state, xs, rw, lc)
-            with span("simnet.device_wait", into, "device_wait_seconds"):
-                lane_total, cycles, overflow = workload_totals(state, packed)
-                jax.block_until_ready(cycles)
-            return time.perf_counter() - t0, lane_total, cycles, overflow
-
-        dt, lane_total, cycles, overflow = one_pass(phases)
-        first_dt = time.perf_counter() - t_start  # compile/cache-hit + staging + run
-        if timeit:
-            dt, lane_total, cycles, overflow = one_pass(None)
+            t0 = time.perf_counter()  # `seconds` starts at the state's put
+            state = init_state(packed.n_lanes, self.sim_cfg)
+            if mesh is not None:
+                state = jax.device_put(state, state_shardings(mesh))
+            # one chunk on the device at a time: device memory stays O(chunk)
+            for c in range(packed.n_chunks):
+                xs = {k: put(v[c], xs_sh.get(k)) for k, v in packed.xs.items()}
+                state = exe(self.params, state, xs, rw, lc)
+        with span("simnet.device_wait", phases, "device_wait_seconds"):
+            lane_total, cycles, overflow = workload_totals(state, packed)
+            jax.block_until_ready(cycles)
+        t_end = time.perf_counter()
         with span("simnet.results", phases, "results_seconds"):
             cycles = np.asarray(cycles, np.float64)
             # Numeric guard: a NaN/Inf anywhere in the predictor's latency
@@ -364,6 +341,7 @@ class SimNetEngine:
             overflow = np.asarray(overflow)
         n_instr = packed.n_instructions
         total_instr = int(n_instr.sum())
+        dt = t_end - t0
         return {
             "workload_cycles": cycles,
             "workload_cpi": cycles / np.maximum(n_instr, 1),
@@ -377,7 +355,7 @@ class SimNetEngine:
             "n_workloads": packed.n_workloads,
             "throughput_ips": total_instr / dt,
             "seconds": dt,
-            "first_call_seconds": first_dt,
+            "first_call_seconds": t_end - t_start,  # pack + compile/cache hit + run
             "cache": self.cache.delta_since(cache_before),
             "pack_buffer": {
                 "reuses": self.pack_buffer_counters["reuses"] - buffer_before["reuses"],
@@ -386,21 +364,6 @@ class SimNetEngine:
                 "bytes": self.pack_buffer_counters["bytes"],
             },
             **phases,
-        }
-
-    # -- single-workload convenience (same packed scan underneath) -----
-
-    def simulate(self, fields: Dict[str, np.ndarray], n_lanes: int, chunk: int = 1024,
-                 timeit: bool = False):
-        res = self.simulate_many([fields], n_lanes=n_lanes, chunk=chunk, timeit=timeit)
-        n = int(res["n_instructions"][0])
-        return {
-            "total_cycles": float(res["workload_cycles"][0]),
-            "cpi": float(res["workload_cpi"][0]),
-            "n_instructions": n,
-            "throughput_ips": res["throughput_ips"],
-            "seconds": res["seconds"],
-            "overflow": int(res["workload_overflow"][0]),
         }
 
 

@@ -50,8 +50,8 @@ def test_simulate_from_loaded_matches_fresh(tmp_path, params, pcfg, loop_trace):
     fresh = SimNet(params=params, pcfg=pcfg)
     fresh.save(tmp_path / "m")
     loaded = SimNet.from_artifact(tmp_path / "m")
-    a = fresh.simulate(loop_trace, n_lanes=4, timeit=False)
-    b = loaded.simulate(loop_trace, n_lanes=4, timeit=False)
+    a = fresh.simulate(loop_trace, n_lanes=4)
+    b = loaded.simulate(loop_trace, n_lanes=4)
     assert a[0].total_cycles == b[0].total_cycles
     assert a[0].cpi == b[0].cpi
     assert a[0].overflow == b[0].overflow

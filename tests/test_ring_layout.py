@@ -214,28 +214,3 @@ def test_cli_simulate_ring_smoke(capsys, tmp_path):
         out = json.loads(capsys.readouterr().out)
         totals[layout] = out["result"]["workloads"][0]["total_cycles"]
     assert totals["ring"] == totals["roll"]
-
-
-@pytest.mark.slow
-def test_step_layout_wall_clock(fields):
-    """The reason the ring layout exists: steady-state packed step
-    throughput beats the roll layout on ctx_len ≥ 64 packs (the
-    acceptance bar is 1.3×; assert a conservative 1.1× so CI noise
-    cannot flake the guard — benchmarks/pipeline.py records the real
-    ratio in packed_throughput.json's step_layout section)."""
-    from repro.serving.compile_cache import CompileCache
-    from repro.serving.simnet_engine import SimNetEngine
-
-    def steady(layout):
-        eng = SimNetEngine(
-            sim_cfg=SimConfig(ctx_len=64, layout=layout), cache=CompileCache()
-        )
-        return min(  # best-of-3: sub-second passes are scheduler-noisy
-            eng.simulate_many(fields, n_lanes=16, chunk=128, timeit=True)["seconds"]
-            for _ in range(3)
-        )
-
-    roll_s, ring_s = steady("roll"), steady("ring")
-    assert ring_s < roll_s / 1.1, (
-        f"ring {ring_s:.3f}s not faster than roll {roll_s:.3f}s"
-    )

@@ -33,15 +33,16 @@ def test_simnet_engine_matches_direct_scan(small_trace):
     scfg = SimConfig(ctx_len=16)
     arrs = F.trace_arrays(small_trace)
     engine = SimNetEngine(params, pcfg, scfg)
-    res_e = engine.simulate(F.trace_fields(small_trace), n_lanes=4, chunk=256)
+    res_e = engine.simulate_many([F.trace_fields(small_trace)], n_lanes=4, chunk=256)
     predict = make_predict_fn(params, pcfg)
     res_d = simulate_trace(arrs, predict, scfg, n_lanes=4)
     # chunked-scan engine must agree with the single-scan reference wherever
     # both consumed the same number of instructions
-    if res_e["n_instructions"] == int(res_d["n_instructions"]):
-        assert res_e["total_cycles"] == pytest.approx(float(res_d["total_cycles"]), rel=1e-6)
+    if int(res_e["n_instructions"][0]) == int(res_d["n_instructions"]):
+        assert float(res_e["workload_cycles"][0]) == pytest.approx(
+            float(res_d["total_cycles"]), rel=1e-6)
     else:
-        assert res_e["cpi"] == pytest.approx(
+        assert float(res_e["workload_cpi"][0]) == pytest.approx(
             float(res_d["total_cycles"]) / int(res_d["n_instructions"]), rel=0.1
         )
 

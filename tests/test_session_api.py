@@ -88,6 +88,34 @@ def test_deprecated_shims_are_gone():
         assert not hasattr(api, name), f"api.{name} should have been removed"
 
 
+def test_simnet_import_loads_no_lm_module():
+    """The SimNet path stands apart from the language-model stack:
+    importing the session API in a fresh interpreter loads none of the
+    LM layers, models, configs or launchers."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    env = {**os.environ, "PYTHONPATH": os.pathsep.join(
+        p for p in (src, os.environ.get("PYTHONPATH")) if p)}
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import json, sys, repro.core.api; "
+         "print(json.dumps(sorted(m for m in sys.modules if m.startswith('repro.'))))"],
+        env=env, capture_output=True, text=True, check=True,
+    )
+    loaded = json.loads(out.stdout.strip().splitlines()[-1])
+    assert "repro.core.api" in loaded
+    lm = {f"repro.nn.{m}" for m in ("attention", "layers", "moe", "rope", "ssm",
+                                   "transformer")}
+    lm |= {f"repro.launch.{m}" for m in ("train", "specs", "dryrun")}
+    bad = [m for m in loaded
+           if m in lm or m.split(".")[:2] in (["repro", "models"], ["repro", "configs"])]
+    assert not bad, f"importing repro.core.api loaded LM modules: {bad}"
+
+
 def test_results_are_frozen_and_json_ready(traces):
     sn = SimNet()
     res = sn.simulate_many(traces[:2], n_lanes=2)

@@ -84,22 +84,22 @@ def test_spans_nest_in_order_on_the_profiler_timeline(kind, traces, tmp_path):
     phases = [ev for ev in spans if ev[0] in PHASES]
     assert all(_within(ev, batch) for ev in phases)
     names = [ev[0] for ev in phases]
-    # stage twice (the lane configs, then the state and chunk enqueues),
+    # one stage (the lane configs, the state and the chunk enqueues),
     # results twice (the engine's copies and guard, then the job results)
-    assert names == ["simnet.pack", "simnet.executable", "simnet.stage", "simnet.stage",
+    assert names == ["simnet.pack", "simnet.executable", "simnet.stage",
                      "simnet.device_wait", "simnet.results", "simnet.results"]
 
 
-@pytest.mark.parametrize("kind,timeit", [("tf", False), ("c3", False), ("c3", True)])
-def test_batch_report_phase_counters(kind, timeit, traces):
+@pytest.mark.parametrize("kind", ["tf", "c3", "tx6"])
+def test_batch_report_phase_counters(kind, traces):
     sn = _session(kind)
-    sn.simulate_many(traces, n_lanes=2, timeit=timeit)
-    sn.simulate_many(traces, n_lanes=2, timeit=timeit)
+    sn.simulate_many(traces, n_lanes=2)
+    sn.simulate_many(traces, n_lanes=2)
     b = sn.service.batches[-1]
     counters = ("featurize_seconds", "pack_seconds", "stage_seconds",
                 "device_wait_seconds", "results_seconds")
     assert all(getattr(b, c) > 0 for c in counters), b
-    # the counters time the first pass only: a timeit re-run adds nothing
+    # the engine's phases all fall inside the call it times
     assert b.pack_seconds + b.stage_seconds + b.device_wait_seconds <= b.first_call_seconds
     d = b.to_dict()
     assert set(counters) <= set(d) and all(isinstance(d[c], float) for c in counters)
